@@ -82,9 +82,12 @@ def write_record(path: str, record: dict) -> None:
 def run_subprocess_devices(code: str, num_devices: int,
                            timeout: int = 600) -> str:
     """Run `code` in a fresh python with N forced host devices; returns
-    stdout (the code prints its own results)."""
+    stdout (the code prints its own results). The child is held to the CPU
+    platform: those devices are virtual, and on a TPU host the parent
+    already holds the chip."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,

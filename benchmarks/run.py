@@ -54,6 +54,9 @@ def parse_args(argv: List[str]) -> Tuple[List[str], bool]:
 
 def main() -> None:
     import importlib
+
+    from repro.launch import compile_cache
+    compile_cache.enable()
     filters, smoke = parse_args(sys.argv[1:])
     if smoke:
         # Before any benchmark module (hence benchmarks.common) imports:

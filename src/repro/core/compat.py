@@ -1,9 +1,6 @@
-"""JAX version compatibility shims.
+"""The one `shard_map` entry point of the SPMD drivers.
 
-`shard_map` moved from `jax.experimental.shard_map` (<= 0.4.x, kwarg
-`check_rep`) to `jax.shard_map` (>= 0.5, kwarg `check_vma`). Every SPMD
-driver in this repo routes through this wrapper so the same source runs on
-both: call `shard_map(f, mesh=..., in_specs=..., out_specs=...)`; replica /
+Every driver calls `shard_map(f, mesh=..., in_specs=..., out_specs=...)`;
 varying-manual-axes checking is always disabled (the k-mer drivers return
 unreduced per-shard results on purpose).
 """
@@ -12,14 +9,7 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5
-    _shard_map = jax.shard_map
-    _CHECK_KWARGS = {"check_vma": False}
-except AttributeError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KWARGS = {"check_rep": False}
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_CHECK_KWARGS)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -20,7 +20,8 @@ capacity scaled by `RetryPolicy.store_growth` (default: doubled,
 tiles, and since this PR the same ENGINE: both loops run through
 `resilience.RetryController`, which records every rehash round
 (`DAKCStats.retry_store_rehash`), enforces the capacity ceiling
-(`RetryPolicy.store_cap_ceiling`, default 1<<28 slots/PE), and gives up
+(`RetryPolicy.store_cap_ceiling`, default `max_store_capacity()`: the
+VMEM-resident table bound on the TPU, 1<<28 slots/PE elsewhere), and gives up
 with a typed `CapacityExhausted` carrying the full round history instead
 of an anonymous RuntimeError. Since the spill tier (core/spill.py) that
 give-up is itself recoverable: with `DAKCConfig.spill='auto'` the
@@ -76,7 +77,7 @@ import jax.numpy as jnp
 
 from repro.core import owner
 from repro.core.sort import AccumResult, accumulate, sort_with_weights
-from repro.kernels import ops
+from repro.kernels import hash_table, ops
 
 
 class CountStore(NamedTuple):
@@ -102,6 +103,17 @@ class StoreSnapshot(NamedTuple):
     counts: jax.Array             # (P * store_cap,) sharded store counts
     store_cap: int                # per-PE slot count of THIS generation
     spill_state: Optional[dict]   # committed manifest view, or None
+
+
+def max_store_capacity() -> int:
+    """Largest per-PE store the backend's insert path holds: the
+    VMEM-resident table bound where the kernels compile for the TPU
+    (`hash_table.max_vmem_capacity`), 2**28 slots elsewhere (the jnp
+    oracle). The retry policy's default store ceiling; past it a store
+    spills (`DAKCConfig.spill='auto'`) instead of failing to compile."""
+    if ops._interpret():
+        return 1 << 28
+    return hash_table.max_vmem_capacity()
 
 
 def empty_store(capacity: int, dtype) -> CountStore:

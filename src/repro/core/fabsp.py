@@ -96,7 +96,8 @@ returned, replays driven by ONE typed retry engine
    in-core, bounded by `max_slack`.
 2. **Rehash.** A full count store doubles its capacity and rehashes the
    committed entries (cause 'store-rehash'), bounded by
-   `store_cap_ceiling` -- the HBM budget.
+   `store_cap_ceiling` -- by default the largest table the insert kernel
+   holds (VMEM on the TPU); no round ever grows past it.
 3. **Spill.** Past the ceiling the in-core discipline is out of moves:
    with `DAKCConfig.spill='auto'` the `CapacityExhausted(store-rehash)`
    give-up is intercepted instead of raised -- the committed store
@@ -268,9 +269,9 @@ class DAKCConfig:
     store_capacity: Optional[int] = None
     # The one retry engine (core/resilience.py): per-cause caps, growth
     # factors, total replay budget. Every retried call -- count_kmers and
-    # KmerCounter.update -- flows through this policy; the default
-    # reproduces the historical hand-rolled loops exactly (slack gives up
-    # past 8, the store past 2**28 slots).
+    # KmerCounter.update -- flows through this policy; by default slack
+    # gives up past 8 and the store at the largest table the backend's
+    # insert kernel holds (2**24 slots of VMEM on the TPU, 2**28 elsewhere).
     retry: resilience.RetryPolicy = resilience.RetryPolicy()
     # Deterministic fault injection: a seeded resilience.FaultPlan naming
     # one site (route_drop / store_drop / hop2_misfit / update_fail /
@@ -884,7 +885,15 @@ def _default_store_capacity(cfg: DAKCConfig, shape, num_pes: int) -> int:
     total = n_reads * (m - cfg.k + 1)
     distinct_bound = min(total,
                          1 << encoding.kmer_bits(cfg.k, cfg.bits_per_symbol))
-    return plan_capacity(distinct_bound, num_pes, cfg.store_slack)
+    return _within_ceiling(
+        plan_capacity(distinct_bound, num_pes, cfg.store_slack), cfg)
+
+
+def _within_ceiling(cap: int, cfg: DAKCConfig) -> int:
+    """A planned store capacity clamped to the retry policy's ceiling: a
+    store that starts there and still overflows spills (or raises
+    CapacityExhausted) instead of asking for a table that cannot compile."""
+    return min(cap, cfg.retry.store_ceiling())
 
 
 def _sampled_distinct_estimate(reads, cfg: DAKCConfig,
@@ -951,7 +960,7 @@ def _sampled_store_capacity(reads, cfg: DAKCConfig, num_pes: int) -> int:
     if est is None:
         return _default_store_capacity(cfg, tuple(reads.shape), num_pes)
     cap = plan_capacity(est, num_pes, cfg.store_slack)
-    return 1 << (cap - 1).bit_length()
+    return _within_ceiling(1 << (cap - 1).bit_length(), cfg)
 
 
 def _resolve_store_capacity(reads, cfg: DAKCConfig, num_pes: int) -> int:
@@ -1682,7 +1691,8 @@ class KmerCounter:
             if cfg.store_capacity is None and self._distinct_est is not None:
                 cap = plan_capacity(self._distinct_est, self._num_pes,
                                     cfg.store_slack)
-                self._store_cap = 1 << (cap - 1).bit_length()
+                self._store_cap = _within_ceiling(
+                    1 << (cap - 1).bit_length(), cfg)
             else:
                 self._store_cap = _resolve_store_capacity(reads, cfg,
                                                           self._num_pes)

@@ -95,20 +95,23 @@ class RetryPolicy:
     """Bounds and growth factors of the one retry engine.
 
     Hashable and frozen: it rides `DAKCConfig` into the executable-cache
-    key. Defaults reproduce the pre-policy hand-rolled loops bit-for-bit:
-    routing slack doubles and gives up once it EXCEEDS `max_slack`; the
-    store doubles and gives up once its capacity EXCEEDS
-    `store_cap_ceiling`; the compact hop 2 falls back to the padded tile
-    at most once (there is no third capacity). `max_rounds` is a total
-    replay budget across all causes -- a backstop against pathological
-    cause ping-pong, set above any legitimate doubling ladder (a 1-slot
-    store reaching the ceiling is ~28 rehash rounds). `max_history` caps
+    key. Routing slack doubles and gives up once it EXCEEDS `max_slack`;
+    the store grows and gives up once growing would take it past
+    `store_cap_ceiling` -- by default (None) the largest store the
+    backend's insert kernel holds (`countstore.max_store_capacity`: the
+    VMEM bound on the TPU, 2**28 slots elsewhere), so no rehash round ever
+    asks for a table that cannot compile; the compact hop 2 falls back to
+    the padded tile at most once (there is no third capacity).
+    `max_rounds` is a total replay budget across all causes -- a backstop
+    against pathological cause ping-pong, set above any legitimate
+    doubling ladder (a 1-slot store reaching the ceiling is at most ~28
+    rehash rounds). `max_history` caps
     the retained round history (first round + ring of the most recent
     `max_history - 1`); it bounds payload size only, never the budget.
     """
     max_slack: float = 8.0
     slack_growth: float = 2.0
-    store_cap_ceiling: int = 1 << 28
+    store_cap_ceiling: Optional[int] = None
     store_growth: int = 2
     max_rounds: int = 40
     max_history: int = 25
@@ -118,7 +121,8 @@ class RetryPolicy:
             raise ValueError(
                 f"need max_slack > 0 and slack_growth > 1, got "
                 f"{self.max_slack}/{self.slack_growth}")
-        if self.store_cap_ceiling < 1 or self.store_growth < 2:
+        if (self.store_cap_ceiling is not None
+                and self.store_cap_ceiling < 1) or self.store_growth < 2:
             raise ValueError(
                 f"need store_cap_ceiling >= 1 and store_growth >= 2, got "
                 f"{self.store_cap_ceiling}/{self.store_growth}")
@@ -128,6 +132,14 @@ class RetryPolicy:
             raise ValueError(
                 f"max_history must be >= 2 (first + at least one recent "
                 f"round), got {self.max_history}")
+
+    def store_ceiling(self) -> int:
+        """Per-PE store slot ceiling: the explicit `store_cap_ceiling`, or
+        the backend's (`countstore.max_store_capacity`)."""
+        if self.store_cap_ceiling is not None:
+            return self.store_cap_ceiling
+        from repro.core import countstore
+        return countstore.max_store_capacity()
 
 
 class RetryRound(NamedTuple):
@@ -349,11 +361,12 @@ class RetryController:
                 f"(> max_slack {self.policy.max_slack}): {route_dropped} "
                 f"entries dropped after {self.own_rounds} round(s)",
                 ROUTE_SLACK, self.rounds, self.counts)
+        ceiling = self.policy.store_ceiling()
         if STORE_REHASH in causes \
-                and self.store_cap > self.policy.store_cap_ceiling:
+                and self.store_cap * self.policy.store_growth > ceiling:
             raise CapacityExhausted(
                 f"count store still overflows at {self.store_cap} slots "
-                f"(> ceiling {self.policy.store_cap_ceiling}): "
+                f"(growing past ceiling {ceiling}): "
                 f"{store_dropped} inserts dropped after "
                 f"{self.own_rounds} round(s)", STORE_REHASH, self.rounds,
                 self.counts)

@@ -11,6 +11,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import encoding
 from repro.core.sort import AccumResult, accumulate
@@ -44,3 +45,27 @@ def count_kmers_python(reads_np, k: int) -> dict:
             if j >= k - 1:
                 c[word] += 1
     return dict(c)
+
+
+def count_kmers_numpy(reads_np, k: int, canonical: bool = False):
+    """Vectorized host oracle: (sorted unique packed words, int64 counts).
+
+    NumPy only -- no JAX, no kernels -- so it checks the device path at
+    sizes the Python loop above cannot reach (tens of millions of k-mer
+    instances). Words pack first base highest, as `count_kmers_python`
+    does; `canonical` keeps min(word, reverse complement) with the
+    complement of code c being 3 - c.
+    """
+    codes = np.asarray(reads_np)
+    n_pos = codes.shape[1] - k + 1
+    dt = np.uint32 if 2 * k <= 30 else np.uint64
+    fwd = np.zeros((codes.shape[0], n_pos), dt)
+    rc = np.zeros_like(fwd)
+    for j in range(k):
+        base = codes[:, j:j + n_pos].astype(dt)
+        fwd = (fwd << dt(2)) | base
+        if canonical:
+            rc |= (dt(3) - base) << dt(2 * j)
+    words = np.minimum(fwd, rc) if canonical else fwd
+    uniq, counts = np.unique(words.ravel(), return_counts=True)
+    return uniq, counts.astype(np.int64)

@@ -7,35 +7,37 @@ chunks in flight. This kernel is that insert, adapted to the TPU's
 static-shape world:
 
 - The table is a fixed-capacity open-addressing array pair (`keys`,
-  `counts`), empty slots keyed by the all-ones sentinel. It lives in one
-  VMEM-resident block with a CONSTANT index map, so the sequential TPU grid
-  revisits (and therefore keeps resident) the same block across every input
-  tile -- the standard accumulator pattern, here carrying a mutable table
-  instead of a partial sum.
-- The grid walks the batch in `tile`-sized chunks; within a tile, items are
-  folded in stream order by a `fori_loop` whose body linear-probes from the
-  caller-supplied home slot (`slots`, hashed OUTSIDE the kernel so the
-  kernel stays dtype-thin) with a bounded `while_loop`: stop at the first
-  empty slot (insert) or matching key (add), wrapping modulo capacity. A
-  probe sweep that visits every slot without landing means the table is
-  full: the item is dropped and counted, and the caller's overflow round
-  doubles the capacity (the same slack-doubling discipline the routing
-  tiles use).
+  `counts`), empty slots keyed by the all-ones sentinel. The kernel views
+  it as rows of `_LANES` slots, `(cap / 128, 128)`, and copies it ONCE from
+  HBM into a VMEM scratch at the first grid step (and back to HBM at the
+  last), so the sequential grid keeps one resident copy across every input
+  tile -- the accumulator pattern, carrying a mutable table instead of a
+  partial sum. Table size is therefore bounded by VMEM (`max_vmem_capacity`;
+  the retry policy's store ceiling stops there on the TPU and growth past
+  it goes to the spill tier).
+- The batch (keys, weights, home slots) arrives in `tile`-sized SMEM blocks:
+  the items are scalars to the probe loop. Slots are hashed OUTSIDE the
+  kernel so the kernel stays dtype-thin; words travel as int32 bit
+  patterns (only equality is ever asked of them).
+- Each item probes row by row: one dynamic row load resolves up to 128
+  linear-probe steps at once (the first lane, at or after the home lane,
+  holding the sentinel or the key), wrapping modulo capacity. A walk that
+  visits every slot without landing means the table is full: the item is
+  dropped and counted, and the caller's overflow round grows the capacity
+  (the same discipline the routing tiles use). A landing writes its row
+  back through a one-lane masked select.
 - Dropped-item counts accumulate in an SMEM carry across grid steps
-  (sequential grid => exact, as in segment_count.py) and are mirrored into
-  a (1,) output each step.
+  (sequential grid => exact, as in segment_count.py).
 
 Determinism: tiles execute in order and items within a tile fold in input
-order, so the final table state is bit-identical to the sequential pure-jnp
-oracle (`ref.hash_insert_ref`) -- slot layout included, not just the
-key->count multiset.
+order, and the row walk lands on exactly the slot a one-slot-at-a-time
+linear probe would, so the final table state is bit-identical to the
+sequential pure-jnp oracle (`ref.hash_insert_ref`) -- slot layout included,
+not just the key->count multiset. The lookup kernel's probe counts are the
+oracle's step counts, recovered from the landing slot's distance to home.
 
-Scalar probing is VPU-hostile (one dynamic load per probe); the design bets
-on the paper's own observation that receiver-side work is a small slice of
-the budget once messages are aggregated. On-TPU tuning (vectorized cuckoo
-rounds, wider probe loads) is future work; in this container the kernel
-runs in interpret mode, where correctness of the tiled algorithm is what
-tests validate.
+The item loop is still sequential (each insert may change the next probe);
+vectorized probe rounds are ROADMAP Speed item 2.
 """
 
 from __future__ import annotations
@@ -44,71 +46,125 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Probe outcomes (int32 codes threaded through the while_loop state).
-_PENDING = 0   # still probing (terminal only when the sweep exhausts the table)
-_INSERT = 1    # landed on an empty slot
-_ADD = 2       # landed on a matching key
+_LANES = 128
+# VMEM of one TPU v5e TensorCore. The table scratch (int32 keys + counts,
+# 8 bytes a slot) is the kernels' only large buffer; the compile rehearsal
+# (tests/test_tpu_compile.py) admits 2^24 slots and refuses 2^25.
+_VMEM_BYTES = 128 * 1024 * 1024
+_VMEM_HEADROOM = 8 * 1024 * 1024
 
 
-def _get(ref, i):
-    return pl.load(ref, (pl.ds(i, 1),))[0]
+def max_vmem_capacity() -> int:
+    """Largest power-of-two slot count whose (keys, counts) int32 table
+    fits one core's VMEM: the per-PE store ceiling on the TPU."""
+    cap = 1
+    while 2 * cap * 8 <= _VMEM_BYTES:
+        cap *= 2
+    return cap
 
 
-def _put(ref, i, v):
-    pl.store(ref, (pl.ds(i, 1),), v[None])
+def _layout(cap: int):
+    """(rows, width) view of a `cap`-slot table: rows of 128 lanes, or one
+    full-width row when `cap` is not a lane multiple (tiny test tables)."""
+    width = _LANES if cap % _LANES == 0 else cap
+    return cap // width, width
 
 
-def _hash_insert_kernel(tkeys_ref, tcounts_ref, keys_ref, w_ref, slots_ref,
-                        okeys_ref, ocounts_ref, ovf_ref, carry_ref, *,
-                        sentinel_val: int):
+def _compiler_params(cap: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=min(cap * 8 + _VMEM_HEADROOM, _VMEM_BYTES))
+
+
+def _sent_i32(sentinel_val: int, word) -> int:
+    if np.dtype(word).itemsize != 4:
+        raise ValueError(f"hash kernels take 32-bit words, got {word}")
+    return int(np.asarray(sentinel_val, np.dtype(word)).view(np.int32))
+
+
+def _as_i32(x):
+    return x if x.dtype == jnp.int32 else jax.lax.bitcast_convert_type(
+        x, jnp.int32)
+
+
+def _walk(tk, key, slot0, sent, active, rows: int, width: int):
+    """Row-wise linear probe from `slot0`: the first slot holding `sent` or
+    `key` in probe order, or -1 when all `rows * width` slots were visited
+    (or the item is not `active`: padding never walks).
+    Visit t covers row (home row + t) mod rows; visit 0 starts at the home
+    lane and the final visit (t == rows) closes the wrap before it."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    r0 = jax.lax.div(slot0, jnp.int32(width))
+    l0 = jax.lax.rem(slot0, jnp.int32(width))
+
+    def probing(state):
+        t, found = state
+        return active & (found < 0) & (t <= rows)
+
+    def visit(state):
+        t, _ = state
+        row = r0 + t
+        row = jnp.where(row >= rows, row - rows, row)
+        lo = jnp.where(t == 0, l0, 0)
+        hi = jnp.where(t == rows, l0, width)
+        cur = tk[pl.ds(row, 1), :]
+        hit = (lane >= lo) & (lane < hi) & ((cur == sent) | (cur == key))
+        first = jnp.min(jnp.where(hit, lane, width))
+        return t + 1, jnp.where(first < width, row * width + first, -1)
+
+    _, found = jax.lax.while_loop(probing, visit,
+                                  (jnp.int32(0), jnp.int32(-1)))
+    return found
+
+
+def _hash_insert_kernel(keys_ref, w_ref, slots_ref, tk_hbm, tc_hbm,
+                        ok_hbm, oc_hbm, ovf_ref, tk, tc, carry, *,
+                        sentinel_val: int, rows: int, width: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        okeys_ref[...] = tkeys_ref[...]
-        ocounts_ref[...] = tcounts_ref[...]
-        carry_ref[0] = jnp.int32(0)
+        pltpu.sync_copy(tk_hbm, tk)
+        pltpu.sync_copy(tc_hbm, tc)
+        carry[0] = jnp.int32(0)
 
-    cap = okeys_ref.shape[0]
-    tile = keys_ref.shape[0]
-    dt = keys_ref.dtype.type
-    sent = dt(sentinel_val)
+    sent = jnp.int32(sentinel_val)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
 
     def fold_one(i, dropped):
-        key = _get(keys_ref, i)
-        w = _get(w_ref, i)
-        slot0 = _get(slots_ref, i)
+        key = keys_ref[i]
+        w = w_ref[i]
         valid = (key != sent) & (w > 0)
+        found = _walk(tk, key, slots_ref[i], sent, valid, rows, width)
+        land = valid & (found >= 0)
 
-        def probing(state):
-            j, _, st = state
-            return valid & (st == _PENDING) & (j < cap)
+        @pl.when(land)
+        def _write():
+            row = jax.lax.div(found, jnp.int32(width))
+            mine = lane == jax.lax.rem(found, jnp.int32(width))
+            cur = tk[pl.ds(row, 1), :]
+            tk[pl.ds(row, 1), :] = jnp.where(mine & (cur == sent), key, cur)
+            tc[pl.ds(row, 1), :] = tc[pl.ds(row, 1), :] + jnp.where(
+                mine, w, jnp.int32(0))
 
-        def probe(state):
-            j, slot, _ = state
-            cur = _get(okeys_ref, slot)
-            st = jnp.where(cur == sent, _INSERT,
-                           jnp.where(cur == key, _ADD, _PENDING))
-            nxt = jnp.where(slot + 1 == cap, 0, slot + 1)
-            return (j + jnp.int32(1),
-                    jnp.where(st == _PENDING, nxt, slot),
-                    st.astype(jnp.int32))
+        return dropped + jnp.where(valid & (found < 0), jnp.int32(1),
+                                   jnp.int32(0))
 
-        _, slot, st = jax.lax.while_loop(
-            probing, probe, (jnp.int32(0), slot0, jnp.int32(_PENDING)))
-        hit = (st == _INSERT) | (st == _ADD)
-        # Branch-free read-modify-write: misses rewrite the slot unchanged.
-        _put(okeys_ref, slot, jnp.where(st == _INSERT, key,
-                                        _get(okeys_ref, slot)))
-        _put(ocounts_ref, slot,
-             _get(ocounts_ref, slot) + jnp.where(hit, w, jnp.int32(0)))
-        return dropped + jnp.where(valid & (st == _PENDING),
-                                   jnp.int32(1), jnp.int32(0))
+    carry[0] = carry[0] + jax.lax.fori_loop(0, keys_ref.shape[0], fold_one,
+                                            jnp.int32(0))
+    ovf_ref[0] = carry[0]
 
-    carry_ref[0] = carry_ref[0] + jax.lax.fori_loop(
-        0, tile, fold_one, jnp.int32(0))
-    ovf_ref[...] = carry_ref[0][None]
+    @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+    def _writeback():
+        pltpu.sync_copy(tk, ok_hbm)
+        pltpu.sync_copy(tc, oc_hbm)
+
+
+def _smem_tile(tile: int):
+    return pl.BlockSpec((tile,), lambda i: (i,),
+                        memory_space=pltpu.SMEM)
 
 
 def hash_insert_pallas(table_keys: jax.Array, table_counts: jax.Array,
@@ -117,7 +173,7 @@ def hash_insert_pallas(table_keys: jax.Array, table_counts: jax.Array,
                        tile: int = 1024, interpret: bool = False):
     """Fold a batch of (key, weight) pairs into the open-addressing table.
 
-    table_keys:   (cap,) word table, empty slots == sentinel_val
+    table_keys:   (cap,) 32-bit word table, empty slots == sentinel_val
     table_counts: (cap,) int32
     keys:    (n,) batch words; sentinel (or weight 0) entries are skipped
     weights: (n,) int32 multiplicities (>= 1 for live entries)
@@ -133,63 +189,66 @@ def hash_insert_pallas(table_keys: jax.Array, table_counts: jax.Array,
     if n % tile != 0:
         raise ValueError(f"n {n} % tile {tile} != 0")
     cap = table_keys.shape[0]
-    grid = (n // tile,)
-    out = pl.pallas_call(
-        functools.partial(_hash_insert_kernel, sentinel_val=sentinel_val),
-        grid=grid,
-        in_specs=[pl.BlockSpec((cap,), lambda i: (0,)),
-                  pl.BlockSpec((cap,), lambda i: (0,)),
-                  pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((cap,), lambda i: (0,)),
-                   pl.BlockSpec((cap,), lambda i: (0,)),
-                   pl.BlockSpec((1,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((cap,), table_keys.dtype),
-                   jax.ShapeDtypeStruct((cap,), jnp.int32),
+    rows, width = _layout(cap)
+    word = table_keys.dtype
+    sent = _sent_i32(sentinel_val, word)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    new_keys, new_counts, ovf = pl.pallas_call(
+        functools.partial(_hash_insert_kernel, sentinel_val=sent, rows=rows,
+                          width=width),
+        grid=(n // tile,),
+        in_specs=[_smem_tile(tile), _smem_tile(tile), _smem_tile(tile),
+                  any_spec, any_spec],
+        out_specs=[any_spec, any_spec,
+                   pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_shape=[jax.ShapeDtypeStruct((rows, width), jnp.int32),
+                   jax.ShapeDtypeStruct((rows, width), jnp.int32),
                    jax.ShapeDtypeStruct((1,), jnp.int32)],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((rows, width), jnp.int32),
+                        pltpu.VMEM((rows, width), jnp.int32),
+                        pltpu.SMEM((1,), jnp.int32)],
+        compiler_params=_compiler_params(cap),
         interpret=interpret,
-    )(table_keys, table_counts, keys, weights.astype(jnp.int32),
-      slots.astype(jnp.int32))
-    new_keys, new_counts, ovf = out
-    return new_keys, new_counts, ovf[0]
+    )(_as_i32(keys), weights.astype(jnp.int32), slots.astype(jnp.int32),
+      _as_i32(table_keys).reshape(rows, width),
+      table_counts.astype(jnp.int32).reshape(rows, width))
+    new_keys = jax.lax.bitcast_convert_type(new_keys.reshape(cap), word)
+    return new_keys, new_counts.reshape(cap), ovf[0]
 
 
-def _hash_lookup_kernel(tkeys_ref, tcounts_ref, keys_ref, slots_ref,
-                        counts_ref, probes_ref, *, sentinel_val: int):
-    cap = tkeys_ref.shape[0]
-    tile = keys_ref.shape[0]
-    dt = keys_ref.dtype.type
-    sent = dt(sentinel_val)
+def _hash_lookup_kernel(keys_ref, slots_ref, tk_hbm, tc_hbm,
+                        counts_ref, probes_ref, tk, tc, *,
+                        sentinel_val: int, rows: int, width: int):
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        pltpu.sync_copy(tk_hbm, tk)
+        pltpu.sync_copy(tc_hbm, tc)
+
+    sent = jnp.int32(sentinel_val)
+    cap = rows * width
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
 
     def probe_one(i, _):
-        key = _get(keys_ref, i)
-        slot0 = _get(slots_ref, i)
+        key = keys_ref[i]
+        slot0 = slots_ref[i]
         valid = key != sent
-
-        def probing(state):
-            j, _, st = state
-            return valid & (st == _PENDING) & (j < cap)
-
-        def probe(state):
-            j, slot, _ = state
-            cur = _get(tkeys_ref, slot)
-            st = jnp.where(cur == sent, _INSERT,
-                           jnp.where(cur == key, _ADD, _PENDING))
-            nxt = jnp.where(slot + 1 == cap, 0, slot + 1)
-            return (j + jnp.int32(1),
-                    jnp.where(st == _PENDING, nxt, slot),
-                    st.astype(jnp.int32))
-
-        j, slot, st = jax.lax.while_loop(
-            probing, probe, (jnp.int32(0), slot0, jnp.int32(_PENDING)))
-        cnt = jnp.where(st == _ADD, _get(tcounts_ref, slot), jnp.int32(0))
-        _put(counts_ref, i, jnp.where(valid, cnt, jnp.int32(0)))
-        _put(probes_ref, i, jnp.where(valid, j, jnp.int32(0)))
+        found = _walk(tk, key, slot0, sent, valid, rows, width)
+        at = jnp.maximum(found, 0)
+        row = jax.lax.div(at, jnp.int32(width))
+        mine = lane == jax.lax.rem(at, jnp.int32(width))
+        cur = jnp.max(jnp.where(mine, tk[pl.ds(row, 1), :], jnp.iinfo(
+            jnp.int32).min))
+        cnt = jnp.max(jnp.where(mine, tc[pl.ds(row, 1), :], 0))
+        match = valid & (found >= 0) & (cur == key)
+        # probe steps: landing distance from home (+1), or the full sweep
+        dist = found - slot0
+        steps = jnp.where(found < 0, cap,
+                          jnp.where(dist < 0, dist + cap, dist) + 1)
+        counts_ref[i] = jnp.where(match, cnt, 0)
+        probes_ref[i] = jnp.where(valid, steps, 0)
         return 0
 
-    jax.lax.fori_loop(0, tile, probe_one, 0)
+    jax.lax.fori_loop(0, keys_ref.shape[0], probe_one, 0)
 
 
 def hash_lookup_pallas(table_keys: jax.Array, table_counts: jax.Array,
@@ -203,7 +262,7 @@ def hash_lookup_pallas(table_keys: jax.Array, table_counts: jax.Array,
     slot's count, an empty slot or an exhausted sweep is a miss (count 0).
     Sentinel keys (query-batch padding) are skipped with count 0.
 
-    table_keys:   (cap,) word table, empty slots == sentinel_val
+    table_keys:   (cap,) 32-bit word table, empty slots == sentinel_val
     table_counts: (cap,) int32
     keys:  (n,) query words; sentinel entries skipped
     slots: (n,) int32 home slots -- hash(key) % cap, computed by the caller
@@ -217,19 +276,21 @@ def hash_lookup_pallas(table_keys: jax.Array, table_counts: jax.Array,
     if n % tile != 0:
         raise ValueError(f"n {n} % tile {tile} != 0")
     cap = table_keys.shape[0]
-    grid = (n // tile,)
-    counts, probes = pl.pallas_call(
-        functools.partial(_hash_lookup_kernel, sentinel_val=sentinel_val),
-        grid=grid,
-        in_specs=[pl.BlockSpec((cap,), lambda i: (0,)),
-                  pl.BlockSpec((cap,), lambda i: (0,)),
-                  pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
-                   pl.BlockSpec((tile,), lambda i: (i,))],
+    rows, width = _layout(cap)
+    sent = _sent_i32(sentinel_val, table_keys.dtype)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_hash_lookup_kernel, sentinel_val=sent, rows=rows,
+                          width=width),
+        grid=(n // tile,),
+        in_specs=[_smem_tile(tile), _smem_tile(tile), any_spec, any_spec],
+        out_specs=[_smem_tile(tile), _smem_tile(tile)],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32),
                    jax.ShapeDtypeStruct((n,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((rows, width), jnp.int32),
+                        pltpu.VMEM((rows, width), jnp.int32)],
+        compiler_params=_compiler_params(cap),
         interpret=interpret,
-    )(table_keys, table_counts.astype(jnp.int32), keys,
-      slots.astype(jnp.int32))
-    return counts, probes
+    )(_as_i32(keys), slots.astype(jnp.int32),
+      _as_i32(table_keys).reshape(rows, width),
+      table_counts.astype(jnp.int32).reshape(rows, width))

@@ -1,11 +1,12 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Backend dispatch: Pallas kernels lower to Mosaic only on TPU. On CPU (this
-container, and any unit-test environment) the wrappers run the kernels in
-`interpret=True` mode -- the kernel *body* executes with real Python/XLA
-semantics, so correctness of the tiled algorithm is what the tests validate.
-`force` overrides for tests; `prefer_ref` routes to the jnp oracle (used by
-the dry-run so the lowered HLO contains no custom calls).
+Backend dispatch: Pallas kernels lower to Mosaic only on TPU. Elsewhere (the
+CPU test environment) the wrappers run the kernels in `interpret=True` mode
+-- the kernel *body* executes with real Python/XLA semantics, so
+correctness of the tiled algorithm is what the tests validate. On the TPU
+every wrapper compiles its kernel; nothing falls back to interpret mode or
+to the `ref.*` oracles, and a 64-bit word (k >= 16) is refused with
+`WideWordUnsupported` rather than routed anywhere else.
 """
 
 from __future__ import annotations
@@ -32,8 +33,30 @@ from repro.kernels.segment_count import (segment_accumulate_pallas,
                                          segment_boundaries_pallas)
 
 
+class WideWordUnsupported(TypeError):
+    """A 64-bit k-mer word reached a Pallas kernel compiled for the TPU.
+
+    Mosaic kernels work on 32-bit lanes, so k >= 16 (a uint64 word,
+    `encoding.kmer_dtype`) cannot run on the chip until words travel as two
+    uint32 lanes (ROADMAP Reach item 3). k <= 15 runs on the TPU today.
+    """
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _interpret_for(*operands) -> bool:
+    """The `interpret` flag for a kernel over `operands`; on the compiled
+    (TPU) path, refuses any 64-bit operand."""
+    if _interpret():
+        return True
+    wide = [str(x.dtype) for x in operands if x.dtype.itemsize == 8]
+    if wide:
+        raise WideWordUnsupported(
+            f"Pallas kernels on the TPU take 32-bit words, got {wide}: "
+            "k >= 16 needs two-lane words (ROADMAP Reach item 3)")
+    return False
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3),
@@ -42,6 +65,10 @@ def _interpret() -> bool:
 def kmer_extract(reads: jax.Array, k: int, bits_per_symbol: int = 2,
                  block_reads: int = 8, *,
                  canonical: bool = False) -> jax.Array:
+    if not _interpret() and bits_per_symbol * k > 30:
+        raise WideWordUnsupported(
+            f"k={k} packs into a 64-bit word; Pallas kernels on the TPU "
+            "take 32-bit words (ROADMAP Reach item 3)")
     return kmer_extract_pallas(reads, k, bits_per_symbol,
                                block_reads=block_reads, canonical=canonical,
                                interpret=_interpret())
@@ -52,11 +79,8 @@ def sliding_min(vals: jax.Array, window: int, block_rows: int = 8,
                 tile: int = 512) -> jax.Array:
     """(n_rows, n_pos) -> (n_rows, n_pos - window + 1) windowed minima
     (minimizer selection; kernels/minimizer.py)."""
-    n_rows = vals.shape[0]
-    if n_rows % block_rows != 0:
-        block_rows = 1
     return sliding_min_pallas(vals, window, block_rows=block_rows, tile=tile,
-                              interpret=_interpret())
+                              interpret=_interpret_for(vals))
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
@@ -65,25 +89,23 @@ def sliding_min_pair(keys: jax.Array, vals: jax.Array, window: int,
     """Min-by-KEY sliding window carrying a value lane: ((n_rows, n_out)
     keys, (n_rows, n_out) vals) -- the hashed minimizer order's selection
     primitive (kernels/minimizer.py)."""
-    n_rows = keys.shape[0]
-    if n_rows % block_rows != 0:
-        block_rows = 1
     return sliding_min_pair_pallas(keys, vals, window, block_rows=block_rows,
-                                   tile=tile, interpret=_interpret())
+                                   tile=tile,
+                                   interpret=_interpret_for(keys, vals))
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def radix_hist(keys: jax.Array, shift: int, digit_bits: int = 4,
                tile: int = 1024) -> jax.Array:
     return radix_hist_pallas(keys, shift, digit_bits, tile=tile,
-                             interpret=_interpret())
+                             interpret=_interpret_for(keys))
 
 
 @functools.partial(jax.jit, static_argnames=("sentinel_val", "tile"))
 def segment_boundaries(sorted_keys: jax.Array, *, sentinel_val: int,
                        tile: int = 1024) -> jax.Array:
     return segment_boundaries_pallas(sorted_keys, sentinel_val, tile=tile,
-                                     interpret=_interpret())
+                                     interpret=_interpret_for(sorted_keys))
 
 
 @functools.partial(jax.jit, static_argnames=("sentinel_val", "tile"))
@@ -91,7 +113,8 @@ def segment_accumulate(sorted_keys: jax.Array, weights: jax.Array, *,
                        sentinel_val: int, tile: int = 1024):
     """Fused boundary + segmented-sum sweep: (is_new, is_end, run_totals)."""
     return segment_accumulate_pallas(sorted_keys, weights, sentinel_val,
-                                     tile=tile, interpret=_interpret())
+                                     tile=tile,
+                                     interpret=_interpret_for(sorted_keys))
 
 
 @functools.partial(jax.jit, static_argnames=("sentinel_val", "tile", "impl"))
@@ -104,10 +127,11 @@ def hash_insert(table_keys: jax.Array, table_counts: jax.Array,
 
     impl: 'auto' = the Pallas kernel on TPU, the bit-identical jnp oracle
     elsewhere. Unlike the other kernels, off-TPU 'auto' does NOT interpret:
-    interpret-mode state discharge turns each scalar probe store into an
-    O(capacity) buffer update (~40x slower than the oracle's in-place
+    interpret-mode state discharge turns each row store into an
+    O(capacity) buffer update (far slower than the oracle's in-place
     scan), so emulation is opt-in ('pallas', what the parity tests run)
-    rather than the CPU default.
+    rather than the CPU default. On the TPU, 'auto' never picks the
+    oracle: a 64-bit table raises `WideWordUnsupported`.
     """
     n = keys.shape[0]
     tile = min(tile, max(8, n))
@@ -120,7 +144,7 @@ def hash_insert(table_keys: jax.Array, table_counts: jax.Array,
         slots = jnp.concatenate([slots.astype(jnp.int32),
                                  jnp.zeros((pad,), jnp.int32)])
     if impl == "auto":
-        impl = "ref" if _interpret() else "pallas"
+        impl = "ref" if _interpret_for(table_keys) else "pallas"
     if impl == "ref":
         return ref.hash_insert_ref(table_keys, table_counts, keys, weights,
                                    slots, sentinel_val)
@@ -128,7 +152,7 @@ def hash_insert(table_keys: jax.Array, table_counts: jax.Array,
         raise ValueError(f"unknown hash_insert impl {impl!r}")
     return hash_insert_pallas(table_keys, table_counts, keys, weights, slots,
                               sentinel_val, tile=tile,
-                              interpret=_interpret())
+                              interpret=_interpret_for(table_keys))
 
 
 @functools.partial(jax.jit, static_argnames=("sentinel_val", "tile", "impl"))
@@ -155,14 +179,14 @@ def hash_lookup(table_keys: jax.Array, table_counts: jax.Array,
         slots = jnp.concatenate([slots.astype(jnp.int32),
                                  jnp.zeros((pad,), jnp.int32)])
     if impl == "auto":
-        impl = "ref" if _interpret() else "pallas"
+        impl = "ref" if _interpret_for(table_keys) else "pallas"
     if impl == "ref":
         counts, probes = ref.hash_lookup_ref(table_keys, table_counts, keys,
                                              slots, sentinel_val)
     elif impl == "pallas":
-        counts, probes = hash_lookup_pallas(table_keys, table_counts, keys,
-                                            slots, sentinel_val, tile=tile,
-                                            interpret=_interpret())
+        counts, probes = hash_lookup_pallas(
+            table_keys, table_counts, keys, slots, sentinel_val, tile=tile,
+            interpret=_interpret_for(table_keys))
     else:
         raise ValueError(f"unknown hash_lookup impl {impl!r}")
     return counts[:n], probes[:n]

@@ -21,17 +21,23 @@ both hops of the hierarchical all_to_all run off a single plan (the second
 hop is a plain transpose of the already-partitioned tile; see
 `aggregation.route_lanes`).
 
-1. `bucket_hist_pallas`: per-tile bucket histogram. Each grid instance
-   histograms a VMEM-resident tile of int32 bucket ids via a broadcast
-   compare against a 2-D iota and a lane reduction -- scatter-free, VPU-only
-   (same structure as radix_hist.py, generalized to arbitrary bucket counts).
+Layout: the stream is viewed as one tile per row, eight rows (tiles) per
+grid step, so every block is a whole (8, 128k) slab. Inside, a tile's
+one-hot matrix puts buckets down the sublanes and elements along the lanes
+-- the orientation the ids arrive in, so no lane->sublane relayout is
+needed; per-bucket columns move to and from lane rows through a masked
+diagonal reduction.
+
+1. `bucket_hist_pallas`: per-tile bucket histogram: a broadcast compare
+   against a sublane iota and a lane reduction -- scatter-free, VPU-only.
 2. `bucket_positions_pallas`: per-tile stable rank + global offset. The
    exclusive prefix over (bucket-major, then tile-major) histograms is a tiny
    (T, B) XLA cumsum; each instance then computes every element's within-tile
-   rank among equal buckets (one-hot cumsum over the *tile*, so the working
-   set is O(tile * B) VMEM, never O(n * B) HBM) and adds its tile's base
-   offset. The emitted positions are a permutation: one XLA scatter finishes
-   the partition. No sort primitive appears anywhere in the lowering.
+   rank among equal buckets (a log-step lane cumsum of the one-hot, so the
+   working set is O(tile * B) VMEM, never O(n * B) HBM) and adds its tile's
+   base offset. The emitted positions are a permutation: one XLA scatter
+   finishes the partition. No sort primitive appears anywhere in the
+   lowering.
 
 Stability: ranks are computed in input order within a tile and tiles are
 offset in input order, so the partition is stable -- bit-identical to a
@@ -46,48 +52,110 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # tile * num_buckets ceiling for partition_plan's auto-clamp: 512K int32
-# elements = 2 MB per (tile, B) temp, ~6 MB live across the 3 temps.
+# elements = 2 MB per (B, tile) temp, ~6 MB live across the 3 temps.
 _VMEM_BUDGET_ELEMS = 512 * 1024
+_LANES = 128
+_ROWS = 8          # tiles per grid step: one per sublane of an int32 vreg
 
 
-def _bucket_hist_kernel(buckets_ref, out_ref, *, num_buckets: int):
-    b = buckets_ref[...]  # (tile,) int32
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (b.shape[0], num_buckets), 1)
-    onehot = (b[:, None] == lanes).astype(jnp.int32)
-    # explicit int32: x64 mode (k=31 words) promotes sum accumulators
-    out_ref[...] = jnp.sum(onehot, axis=0,
-                           dtype=jnp.int32).reshape(1, num_buckets)
+def _pad_to(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _onehot(row, nb_s: int):
+    """(1, tile) bucket ids -> (nb_s, tile) int32 one-hot, buckets down the
+    sublanes and elements along the lanes (no lane->sublane relayout)."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, (nb_s, row.shape[1]), 0)
+    return (ids == row).astype(jnp.int32)
+
+
+def _diag(nb_s: int, nb_l: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (nb_s, nb_l), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (nb_s, nb_l), 1))
+
+
+def _col_to_row(col, nb_l: int):
+    """(nb_s, 1) -> (1, nb_l): a transpose as a masked diagonal reduction."""
+    return jnp.sum(jnp.where(_diag(col.shape[0], nb_l), col, 0), axis=0,
+                   keepdims=True, dtype=jnp.int32)
+
+
+def _row_to_col(row, nb_s: int):
+    """(1, nb_l) -> (nb_s, 1), the inverse of `_col_to_row`."""
+    return jnp.sum(jnp.where(_diag(nb_s, row.shape[1]), row, 0), axis=1,
+                   keepdims=True, dtype=jnp.int32)
+
+
+def _exclusive_lane_cumsum(x):
+    """Exclusive prefix sum along the lanes: log-step shifted adds."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    acc, s = x, 1
+    while s < x.shape[1]:
+        acc = acc + jnp.where(lane >= s, pltpu.roll(acc, s, 1), 0)
+        s *= 2
+    return acc - x
+
+
+def _per_tile(body):
+    """Run `body(row)` for each of the block's 8 tiles (a rolled loop keeps
+    Mosaic's compile time flat in the bucket count)."""
+    def step(r, carry):
+        body(pl.ds(r, 1))
+        return carry
+    jax.lax.fori_loop(0, _ROWS, step, 0)
+
+
+def _bucket_hist_kernel(buckets_ref, out_ref, *, nb_s: int):
+    def one_tile(row):
+        oh = _onehot(buckets_ref[row, :], nb_s)
+        col = jnp.sum(oh, axis=1, keepdims=True, dtype=jnp.int32)
+        out_ref[row, :] = _col_to_row(col, out_ref.shape[1])
+    _per_tile(one_tile)
+
+
+def _tile_rows(x, tile: int):
+    """(n,) -> (T8, tile): one tile per row, rows padded to whole 8-row
+    blocks with bucket 0 (filler rows are sliced off the outputs)."""
+    t = x.shape[0] // tile
+    return jnp.pad(x.astype(jnp.int32).reshape(t, tile),
+                   ((0, _pad_to(t, _ROWS) - t), (0, 0)))
 
 
 def bucket_hist_pallas(buckets: jax.Array, num_buckets: int, tile: int = 1024,
                        interpret: bool = False) -> jax.Array:
-    """(n,) int32 bucket ids -> (n//tile, num_buckets) per-tile histograms."""
+    """(n,) int32 bucket ids -> (n//tile, num_buckets) per-tile histograms.
+
+    Each grid step histograms 8 tiles; the bucket axis is padded to 8
+    sublanes inside the kernel and to 128 lanes in the output block."""
     n = buckets.shape[0]
     if n % tile != 0:
         raise ValueError(f"n {n} % tile {tile} != 0")
-    grid = (n // tile,)
-    return pl.pallas_call(
-        functools.partial(_bucket_hist_kernel, num_buckets=num_buckets),
-        grid=grid,
-        in_specs=[pl.BlockSpec((tile,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1, num_buckets), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // tile, num_buckets), jnp.int32),
+    x = _tile_rows(buckets, tile)
+    nb_s, nb_l = _pad_to(num_buckets, _ROWS), _pad_to(num_buckets, _LANES)
+    out = pl.pallas_call(
+        functools.partial(_bucket_hist_kernel, nb_s=nb_s),
+        grid=(x.shape[0] // _ROWS,),
+        in_specs=[pl.BlockSpec((_ROWS, tile), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((_ROWS, nb_l), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((x.shape[0], nb_l), jnp.int32),
         interpret=interpret,
-    )(buckets)
+    )(x)
+    return out[:n // tile, :num_buckets]
 
 
-def _bucket_pos_kernel(buckets_ref, base_ref, out_ref, *, num_buckets: int):
-    b = buckets_ref[...]  # (tile,) int32
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (b.shape[0], num_buckets), 1)
-    onehot = (b[:, None] == lanes).astype(jnp.int32)
-    within = jnp.cumsum(onehot, axis=0,
-                        dtype=jnp.int32) - onehot     # stable rank in tile
-    base = base_ref[...]                              # (1, num_buckets)
-    # Select own-bucket lane without a gather: onehot is 1 exactly once/row.
-    out_ref[...] = jnp.sum((within + base) * onehot, axis=1, dtype=jnp.int32)
+def _bucket_pos_kernel(buckets_ref, base_ref, out_ref, *, nb_s: int):
+    def one_tile(row):
+        oh = _onehot(buckets_ref[row, :], nb_s)
+        within = _exclusive_lane_cumsum(oh)  # stable rank in tile
+        base = _row_to_col(base_ref[row, :], nb_s)
+        # Select own-bucket row without a gather: oh is 1 once per column.
+        out_ref[row, :] = jnp.sum(oh * (within + base), axis=0,
+                                  keepdims=True, dtype=jnp.int32)
+    _per_tile(one_tile)
 
 
 def bucket_positions_pallas(buckets: jax.Array, base: jax.Array,
@@ -105,16 +173,21 @@ def bucket_positions_pallas(buckets: jax.Array, base: jax.Array,
     if n % tile != 0:
         raise ValueError(f"n {n} % tile {tile} != 0")
     num_buckets = base.shape[1]
-    grid = (n // tile,)
-    return pl.pallas_call(
-        functools.partial(_bucket_pos_kernel, num_buckets=num_buckets),
-        grid=grid,
-        in_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((1, num_buckets), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+    x = _tile_rows(buckets, tile)
+    nb_s, nb_l = _pad_to(num_buckets, _ROWS), _pad_to(num_buckets, _LANES)
+    base = jnp.pad(base.astype(jnp.int32),
+                   ((0, x.shape[0] - base.shape[0]),
+                    (0, nb_l - num_buckets)))
+    out = pl.pallas_call(
+        functools.partial(_bucket_pos_kernel, nb_s=nb_s),
+        grid=(x.shape[0] // _ROWS,),
+        in_specs=[pl.BlockSpec((_ROWS, tile), lambda i: (i, 0)),
+                  pl.BlockSpec((_ROWS, nb_l), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((_ROWS, tile), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int32),
         interpret=interpret,
-    )(buckets, base)
+    )(x, base)
+    return out[:n // tile].reshape(n)
 
 
 class PartitionPlan(NamedTuple):
@@ -166,11 +239,12 @@ def make_partition_plan(buckets: jax.Array, num_buckets: int,
     accept a pure tail region). Real elements always land in [0, n).
     """
     n = buckets.shape[0]
-    tile = min(tile, max(8, n))
-    # VMEM budget: the kernels materialize ~3 (tile, B) int32 arrays; clamp
-    # tile so large bucket counts (num_pes at paper scale) stay well inside
-    # the ~16 MB/core VMEM instead of failing to lower.
-    tile = max(8, min(tile, _VMEM_BUDGET_ELEMS // num_buckets))
+    # Whole 128-lane tiles, no larger than the stream needs. VMEM budget:
+    # the kernels materialize ~3 (B, tile) int32 arrays; clamp tile so large
+    # bucket counts (num_pes at paper scale) stay well inside the scoped
+    # VMEM instead of failing to lower.
+    tile = min(tile, _pad_to(n, _LANES), _VMEM_BUDGET_ELEMS // num_buckets)
+    tile = max(_LANES, tile // _LANES * _LANES)
     pad = (-n) % tile
     if pad:
         buckets = jnp.concatenate(

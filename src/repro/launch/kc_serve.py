@@ -365,6 +365,8 @@ def main() -> None:
                     help="with --demo: also run a serving loop of N "
                          "single-tenant requests and print QPS/latency")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     if args.demo:
         run_demo(args.requests)
         return

@@ -197,3 +197,15 @@ def test_flash_blocks_do_not_change_result():
     a = ops.flash_attention(q, k, v, block_q=32, block_k=32)
     b = ops.flash_attention(q, k, v, block_q=128, block_k=64)
     assert float(jnp.abs(a - b).max()) < 2e-5
+
+
+def test_wide_word_refused_on_compiled_path(monkeypatch):
+    """On the TPU a 64-bit word (k >= 16) raises the typed error naming
+    the two-lane-word roadmap item; it is never routed to the jnp oracle
+    or to interpret mode."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    with pytest.raises(ops.WideWordUnsupported, match="Reach item 3"):
+        ops._interpret_for(np.zeros(4, np.uint32), np.zeros(4, np.uint64))
+    assert ops._interpret_for(np.zeros(4, np.uint32)) is False
+    monkeypatch.setattr(ops, "_interpret", lambda: True)
+    assert ops._interpret_for(np.zeros(4, np.uint64)) is True

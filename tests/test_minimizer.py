@@ -80,7 +80,7 @@ def _serial_dict(reads_arr, k, canonical=False):
 # --- property: sliding-window minimum ----------------------------------------
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(n_pos=st.integers(4, 700), window=st.integers(1, 48),
        seed=st.integers(0, 10_000))
 def test_sliding_min_selects_true_window_minimum(n_pos, window, seed):
@@ -114,7 +114,7 @@ def test_sliding_min_kernel_matches_ref_across_tilings():
 # --- property: segmentation covers every k-mer exactly once ------------------
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(k=st.integers(5, 15), m=st.integers(3, 9),
        heavy=st.booleans(), seed=st.integers(0, 1000))
 def test_superkmers_cover_every_kmer_exactly_once(k, m, heavy, seed):
@@ -143,7 +143,7 @@ def test_superkmers_cover_poly_a_capped_runs():
     assert int(np.asarray(sk.lengths).max()) == minimizer.window_size(k, m)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(seed=st.integers(0, 1000))
 def test_superkmers_canonical_strand_invariant(seed):
     """Canonical mode: a read and its reverse complement select identical

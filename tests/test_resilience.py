@@ -151,6 +151,35 @@ def test_controller_store_ceiling():
     assert ei.value.cause == resilience.STORE_REHASH
 
 
+def test_controller_never_grows_past_ceiling():
+    """A rehash round that would ask for more than the ceiling gives up
+    instead: no executable is ever built for a table past it."""
+    ctrl = RetryController(RetryPolicy(store_cap_ceiling=128), slack=1.0,
+                           store_cap=128)
+    with pytest.raises(CapacityExhausted) as ei:
+        ctrl.observe(store_dropped=1)
+    assert ctrl.store_cap == 128
+    assert ei.value.rounds[-1].store_cap == 128
+
+
+def test_default_store_ceiling_follows_backend(monkeypatch):
+    from repro.core import countstore
+    from repro.kernels import hash_table, ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    assert RetryPolicy().store_ceiling() == hash_table.max_vmem_capacity()
+    assert RetryPolicy().store_ceiling() == 1 << 24
+    assert RetryPolicy(store_cap_ceiling=64).store_ceiling() == 64
+    monkeypatch.setattr(ops, "_interpret", lambda: True)
+    assert countstore.max_store_capacity() == 1 << 28
+
+
+def test_planned_store_stops_at_ceiling(mesh, reads):
+    """A sampled store size past the ceiling starts AT the ceiling."""
+    cfg = fabsp.DAKCConfig(k=11, chunk_reads=16, store_sizing="bound",
+                           retry=RetryPolicy(store_cap_ceiling=64))
+    assert fabsp._resolve_store_capacity(reads, cfg, 1) == 64
+
+
 def test_controller_round_budget():
     ctrl = RetryController(RetryPolicy(max_slack=1e9, max_rounds=2),
                            slack=1.0, store_cap=64)

@@ -79,7 +79,7 @@ def _serial_dict(reads, k):
 # --- property: route_tiles conformance ---------------------------------------
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 1000), num_pes=st.integers(1, 8),
        capacity=st.integers(4, 64), n_word=st.integers(1, 3),
        n_i32=st.integers(0, 2))

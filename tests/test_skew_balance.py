@@ -57,7 +57,7 @@ def _merge(res):
 # --- property: hashed order selects the order_key minimum --------------------
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(n_pos=st.integers(4, 300), window=st.integers(1, 24),
        seed=st.integers(0, 10_000))
 def test_sliding_min_pair_selects_order_key_minimum(n_pos, window, seed):
@@ -88,7 +88,7 @@ def test_order_key_distinct_from_other_families():
     assert (ok != np.asarray(spill.bin_of(x, 1 << 30))).any()
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(k=st.integers(5, 15), m=st.integers(3, 9), seed=st.integers(0, 1000))
 def test_hashed_superkmers_cover_every_kmer_exactly_once(k, m, seed):
     m = min(m, k)
@@ -107,7 +107,7 @@ def test_hashed_superkmers_cover_every_kmer_exactly_once(k, m, seed):
     assert int(np.asarray(sk.lengths).max()) <= w
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(k=st.integers(5, 13), m=st.integers(3, 7), seed=st.integers(0, 1000))
 def test_canonical_minimizers_strand_invariant_both_orders(k, m, seed):
     m = min(m, k)
@@ -134,7 +134,7 @@ def test_unknown_order_rejected():
 # --- compact_lanes unit seam -------------------------------------------------
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(n=st.integers(8, 600), cap=st.integers(4, 256),
        seed=st.integers(0, 1000))
 def test_compact_lanes_prefix_semantics(n, cap, seed):
