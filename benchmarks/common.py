@@ -11,7 +11,7 @@ import os
 import subprocess
 import sys
 import time
-from typing import Callable, Tuple
+from typing import Callable
 
 SCALE = float(os.environ.get("BENCH_SCALE", "1"))
 # --smoke (benchmarks/run.py): toy sizes, single timing rep, no record files.
@@ -27,27 +27,6 @@ def best_of(fn: Callable[[], None], n: int = 3) -> float:
         fn()
         times.append(time.perf_counter() - t0)
     return min(times)
-
-
-def timed(fn: Callable[..., object], *args) -> Tuple[float, float]:
-    """(compile_seconds, steady_seconds) of a jittable callable.
-
-    The first (tracing + compiling) call is timed separately from the
-    best-of steady-state loop, so compile time never pollutes the per-stage
-    record (the l3_compress anomaly of BENCH_phase_breakdown.json v1).
-    """
-    import jax
-
-    jitted = jax.jit(fn)
-    t0 = time.perf_counter()
-    out = jitted(*args)
-    jax.tree.map(lambda x: x.block_until_ready(), out)
-    compile_s = time.perf_counter() - t0
-
-    def go():
-        r = jitted(*args)
-        jax.tree.map(lambda x: x.block_until_ready(), r)
-    return compile_s, best_of(go)
 
 
 def report(name: str, seconds: float, derived: str = "") -> None:

@@ -25,7 +25,6 @@ MODULES = [
     ("fig10.weak_scaling", "benchmarks.weak_scaling"),
     ("fig11.topology", "benchmarks.topology"),
     ("fig12.aggregation_ablation", "benchmarks.aggregation_ablation"),
-    ("perf.phase_breakdown", "benchmarks.phase_breakdown"),
     ("perf.stream_receiver", "benchmarks.stream_receiver"),
     ("perf.superkmer_transport", "benchmarks.superkmer_transport"),
     ("perf.route_lanes", "benchmarks.route_lanes"),

@@ -22,9 +22,8 @@ the 1d topology and in the 2d (2x2) topology, checks that the reads and
 the count store are sharded over all four chips, and compares both
 histograms with the oracle; no other phase runs.
 
-Timings are printed as bring-up observations (first call, which includes
-compilation, and steady calls); they are not benchmark results. The last
-line of standard output is one JSON object:
+It times nothing: `bench/` is the benchmark. The last line of standard
+output is one JSON object:
 {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}.
 """
 
@@ -34,7 +33,6 @@ import argparse
 import json
 import shutil
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -121,16 +119,9 @@ def count_phase(mesh, reads_np: np.ndarray, cfg, oracle, workdir: Path,
 
     kc = fabsp.KmerCounter(mesh, cfg)
     sharding = NamedSharding(mesh, P("pe"))
-    times = []
     for part in np.array_split(reads_np, batches):
-        batch = jax.device_put(part, sharding)
-        t0 = time.perf_counter()
-        stats = kc.update(batch)     # returns after the host reads stats
-        times.append(time.perf_counter() - t0)
-    t0 = time.perf_counter()
+        stats = kc.update(jax.device_put(part, sharding))
     result, fstats = kc.finalize()
-    jax.block_until_ready(result.unique)
-    t_fin = time.perf_counter() - t0
     check_histogram("count (KmerCounter, 1 chip)",
                     histogram(result, kc._num_pes), oracle)
     tier = ("spill tier engaged" if fstats.spilled_bins
@@ -139,10 +130,6 @@ def count_phase(mesh, reads_np: np.ndarray, cfg, oracle, workdir: Path,
         f"{fstats.retry_store_rehash}, route slack rounds "
         f"{fstats.retry_route_slack}; last batch raw k-mers "
         f"{int(stats.raw_kmers)}")
-    steady = times[1:] or times
-    log(f"bring-up observation: first update {times[0]:.3f} s (includes "
-        f"compilation), steady update {np.mean(steady):.3f} s per "
-        f"{reads_np.shape[0] // batches} reads, finalize {t_fin:.3f} s")
     return kc
 
 
@@ -160,7 +147,6 @@ def serve_phase(mesh, kc, cfg, oracle, workdir: Path, seed: int,
     rng = np.random.default_rng(seed + 7)
     ou = oracle[0]
     dt = ou.dtype
-    times = []
     for r in range(flushes):
         batch = []
         for _ in range(requests):
@@ -174,9 +160,7 @@ def serve_phase(mesh, kc, cfg, oracle, workdir: Path, seed: int,
             batch.append(q)
         for q in batch:
             service.submit("genome", q)
-        t0 = time.perf_counter()
         out = service.flush()
-        times.append(time.perf_counter() - t0)
         n_hit = n_miss = 0
         for q, ans in zip(batch, out):
             if isinstance(ans, Exception):
@@ -191,11 +175,8 @@ def serve_phase(mesh, kc, cfg, oracle, workdir: Path, seed: int,
             n_miss += int((want == 0).sum())
         if n_miss == 0 or n_hit == 0:
             raise SmokeFailure("serve: a flush lacked hits or misses")
-    log(f"serve: {len(times)} flushes x {requests} requests x "
+    log(f"serve: {flushes} flushes x {requests} requests x "
         f"{per_request} queries, every answer exact against the oracle")
-    log(f"bring-up observation: first flush {times[0]:.3f} s (includes "
-        f"restore-side compilation), steady flush "
-        f"{np.mean(times[1:]):.3f} s per {requests * per_request} queries")
 
 
 def mesh_phase(devices, reads_np: np.ndarray, cfg, oracle) -> None:
@@ -219,12 +200,7 @@ def mesh_phase(devices, reads_np: np.ndarray, cfg, oracle) -> None:
         if len(shard_devs) != n or rows != {reads_np.shape[0] // n}:
             raise SmokeFailure(f"{topo}: reads not split over {n} chips")
         tcfg = dataclasses.replace(cfg, topology=topo)
-        times = []
-        for _ in range(2):
-            t0 = time.perf_counter()
-            result, stats = fabsp.count_kmers(reads, mesh, tcfg, axes)
-            jax.block_until_ready(result.unique)
-            times.append(time.perf_counter() - t0)
+        result, stats = fabsp.count_kmers(reads, mesh, tcfg, axes)
         store_devs = {s.device for s in result.unique.addressable_shards}
         if len(store_devs) != n:
             raise SmokeFailure(f"{topo}: count store not sharded over {n} "
@@ -234,9 +210,6 @@ def mesh_phase(devices, reads_np: np.ndarray, cfg, oracle) -> None:
         log(f"{topo}: reads and count store sharded over {n} chips; wire "
             f"bytes {int(stats.wire_bytes)}, load max/mean "
             f"{float(stats.load_max_over_mean):.3f}")
-        log(f"bring-up observation: {topo} first count_kmers "
-            f"{times[0]:.3f} s (includes compilation), second "
-            f"{times[1]:.3f} s")
 
 
 def main(argv=None) -> int:
@@ -266,13 +239,11 @@ def main(argv=None) -> int:
 
     from repro.core import fabsp, serial
 
-    t0 = time.perf_counter()
     reads_np = make_reads(args.seed)
     oracle = serial.count_kmers_numpy(reads_np, K, canonical=True)
     log(f"data: {reads_np.shape[0]} reads x {READ_LEN} bp from a "
         f"{GENOME_BASES}-base genome, {oracle[0].size} distinct canonical "
-        f"{K}-mers; made with the oracle in "
-        f"{time.perf_counter() - t0:.1f} s")
+        f"{K}-mers")
 
     workdir = ROOT / ".chip_smoke"
     shutil.rmtree(workdir, ignore_errors=True)

@@ -41,8 +41,8 @@ every lane in one place (per-slot byte width summed over lanes; headers and
 counts are int32 = 4 bytes, word lanes their dtype width). `route_tiles` is
 the pre-collective stage (the L2 tile build), exposed for the conformance
 property tests (tests/test_routing.py) and for `bucket_by_owner`, the
-two-lane wrapper kept for its external users (benchmarks/phase_breakdown
-and the partition-plan test surfaces).
+two-lane wrapper kept for its external users (the partition-plan test
+surfaces).
 
 Pre-route compaction seam (`compact_lanes`): positional extraction layouts
 arrive mostly invalid (one slot per k-mer position, valid only at run

@@ -172,6 +172,7 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import (aggregation, compat, countstore, encoding, minimizer,
@@ -577,37 +578,43 @@ def _phase1_step(chunk, *, cfg: DAKCConfig, num_pes: int, cap_n: int,
     if mode == "superkmer":
         # Minimizer transport: route packed super-k-mer windows, not
         # k-mers. Extraction moves to the receiver (_recv_pairs).
-        sk = minimizer.segment_superkmers(
-            chunk, k, cfg.minimizer_len, bps, canonical=cfg.canonical,
-            canonical_impl=cfg.canonical_impl, order=cfg.minimizer_order)
+        with jax.named_scope("extract"):
+            sk = minimizer.segment_superkmers(
+                chunk, k, cfg.minimizer_len, bps, canonical=cfg.canonical,
+                canonical_impl=cfg.canonical_impl, order=cfg.minimizer_order)
         raw = jnp.int32(sk.lengths.shape[0])   # one slot per k-mer instance
         n_lanes = sk.words.shape[1]
         sk_valid, injected = inject_drop(sk.lengths > 0)
-        lanes = tuple(sk.words[:, s] for s in range(n_lanes)) + (sk.lengths,)
-        kinds = ("word",) * n_lanes + ("i32",)
-        owners = owner_pe(sk.minimizers, num_pes)
-        cap, covf = cap_n, jnp.int32(0)
-        if cc_n is not None and cc_n < sk.lengths.shape[0]:
-            out, sk_valid, covf = aggregation.compact_lanes(
-                lanes + (owners,), kinds + ("i32",), sk_valid, cc_n,
-                impl=cfg.partition_impl)
-            lanes, owners, cap = out[:-1], out[-1], rc_n
-        rr = aggregation.route_lanes(
-            lanes, kinds, owners, sk_valid,
-            num_pes=num_pes, capacity=cap, axis_names=axis_names,
-            grid=grid, impl=cfg.partition_impl, route2d="oneplan",
-            hop2_capacity=h2n)
-        rw = jnp.stack(rr.lanes[:-1], axis=1)
+        with jax.named_scope("route"):
+            lanes = (tuple(sk.words[:, s] for s in range(n_lanes))
+                     + (sk.lengths,))
+            kinds = ("word",) * n_lanes + ("i32",)
+            owners = owner_pe(sk.minimizers, num_pes)
+            cap, covf = cap_n, jnp.int32(0)
+            if cc_n is not None and cc_n < sk.lengths.shape[0]:
+                out, sk_valid, covf = aggregation.compact_lanes(
+                    lanes + (owners,), kinds + ("i32",), sk_valid, cc_n,
+                    impl=cfg.partition_impl)
+                lanes, owners, cap = out[:-1], out[-1], rc_n
+            rr = aggregation.route_lanes(
+                lanes, kinds, owners, sk_valid,
+                num_pes=num_pes, capacity=cap, axis_names=axis_names,
+                grid=grid, impl=cfg.partition_impl, route2d="oneplan",
+                hop2_capacity=h2n)
+            rw = jnp.stack(rr.lanes[:-1], axis=1)
         return (rw, rr.lanes[-1], None), (raw, rr.sent_valid, rr.wire_bytes,
                                           rr.overflow + covf + injected,
                                           rr.hop2_dropped, rr.fill)
 
-    words = encoding.extract_kmers(chunk, k, bps, canonical=cfg.canonical,
-                                   canonical_impl=cfg.canonical_impl)
+    with jax.named_scope("extract"):
+        words = encoding.extract_kmers(chunk, k, bps,
+                                       canonical=cfg.canonical,
+                                       canonical_impl=cfg.canonical_impl)
     raw = jnp.int32(words.shape[0])
     valid = jnp.ones(words.shape, bool)
     mask = encoding.kmer_mask(k, bps)
 
+    @jax.named_scope("route")
     def route(payload, counts, pvalid, capacity, hop2, ccap, rcap):
         lanes = (payload,) if counts is None else (payload, counts)
         kinds = ("word",) if counts is None else ("word", "i32")
@@ -628,7 +635,9 @@ def _phase1_step(chunk, *, cfg: DAKCConfig, num_pes: int, cap_n: int,
 
     if mode == "packed":
         from repro.core.aggregation import l3_compress
-        payload, pvalid = l3_compress(words, k, bps, impl=cfg.phase2_impl)
+        with jax.named_scope("l3"):
+            payload, pvalid = l3_compress(words, k, bps,
+                                          impl=cfg.phase2_impl)
         pvalid, injected = inject_drop(pvalid)
         rr, covf = route(payload, None, pvalid, cap_n, h2n, cc_n, rc_n)
         return (rr.lanes[0], None, None), (raw, rr.sent_valid, rr.wire_bytes,
@@ -636,8 +645,9 @@ def _phase1_step(chunk, *, cfg: DAKCConfig, num_pes: int, cap_n: int,
                                            rr.hop2_dropped, rr.fill)
 
     if mode == "dual":
-        nw, nv, hw, hc, hv = _l3_split_dual(words, valid, k, bps,
-                                            impl=cfg.phase2_impl)
+        with jax.named_scope("l3"):
+            nw, nv, hw, hc, hv = _l3_split_dual(words, valid, k, bps,
+                                                impl=cfg.phase2_impl)
         nv, injected = inject_drop(nv)
         rn, covn = route(nw, None, nv, cap_n, h2n, cc_n, rc_n)
         rh, covh = route(hw, hc, hv, cap_h, h2h, cc_h, rc_h)
@@ -756,22 +766,9 @@ def _stream_fold(chunks, store: countstore.CountStore, *, cfg: DAKCConfig,
             chunk, cfg=cfg, num_pes=num_pes, cap_n=cap_n, cap_h=cap_h,
             mode=mode, axis_names=axis_names, grid=grid, hop2_caps=hop2_caps,
             compact_caps=compact_caps, chunk_idx=cidx, fault=fault)
-        kmers, cnts = _recv_pairs(recv, cfg=cfg, mode=mode)
-        if fault is not None and fault.site == "store_drop":
-            hit = resilience.fault_mask(kmers.shape[0], fault, cidx)
-            if fault.fill > 0:
-                sent_k = jnp.array(jnp.iinfo(st.keys.dtype).max,
-                                   st.keys.dtype)
-                occupied = jnp.sum(st.keys != sent_k)
-                hit = hit & (occupied.astype(jnp.float32)
-                             >= fault.fill * st.keys.shape[0])
-            drop = hit & (cnts > 0)
-            st = countstore.store_insert(st, kmers,
-                                         jnp.where(drop, 0, cnts))
-            st = st._replace(dropped=st.dropped
-                             + jnp.sum(drop).astype(jnp.int32))
-        else:
-            st = countstore.store_insert(st, kmers, cnts)
+        with jax.named_scope("insert"):
+            st = _fold_recv(recv, st, cfg=cfg, mode=mode, fault=fault,
+                            cidx=cidx)
         whi, wlo = _wire_add(whi, wlo, wire)
         # explicit int32: x64 mode (k=31 words) promotes reductions to int64
         return (raw_t + raw.astype(jnp.int32),
@@ -787,6 +784,24 @@ def _stream_fold(chunks, store: countstore.CountStore, *, cfg: DAKCConfig,
         step, (zero, zero, zero, zero, zero, zero, zfill, store),
         (chunks, chunk_ids))
     return store, (raw, sent_w, whi, wlo, ovf, h2, fill)
+
+
+def _fold_recv(recv, st: countstore.CountStore, *, cfg: DAKCConfig,
+               mode: str, fault, cidx) -> countstore.CountStore:
+    """Decode one step's received tiles and insert them into the store
+    (the `insert` layer of `_stream_fold`'s scan step)."""
+    kmers, cnts = _recv_pairs(recv, cfg=cfg, mode=mode)
+    if fault is None or fault.site != "store_drop":
+        return countstore.store_insert(st, kmers, cnts)
+    hit = resilience.fault_mask(kmers.shape[0], fault, cidx)
+    if fault.fill > 0:
+        sent_k = jnp.array(jnp.iinfo(st.keys.dtype).max, st.keys.dtype)
+        occupied = jnp.sum(st.keys != sent_k)
+        hit = hit & (occupied.astype(jnp.float32)
+                     >= fault.fill * st.keys.shape[0])
+    drop = hit & (cnts > 0)
+    st = countstore.store_insert(st, kmers, jnp.where(drop, 0, cnts))
+    return st._replace(dropped=st.dropped + jnp.sum(drop).astype(jnp.int32))
 
 
 def _chunked(reads_local: jax.Array, chunk_reads: int) -> jax.Array:
@@ -1405,10 +1420,11 @@ def _finalize_executable(cfg: DAKCConfig, mesh: Mesh, axis_names,
     total_bits = encoding.kmer_bits(cfg.k, cfg.bits_per_symbol)
 
     def local_finalize(skeys, scounts):
-        res = countstore.store_histogram(
-            countstore.CountStore(keys=skeys, counts=scounts,
-                                  dropped=jnp.int32(0)),
-            total_bits=total_bits, impl=cfg.phase2_impl)
+        with jax.named_scope("finalize"):
+            res = countstore.store_histogram(
+                countstore.CountStore(keys=skeys, counts=scounts,
+                                      dropped=jnp.int32(0)),
+                total_bits=total_bits, impl=cfg.phase2_impl)
         return AccumResult(unique=res.unique, counts=res.counts,
                            num_unique=res.num_unique.reshape(1))
 
@@ -1746,88 +1762,107 @@ class KmerCounter:
         the committed store to bins and replays THIS batch through the
         spill path (exactly-once: the committed store is untouched until
         a batch folds cleanly, so nothing double-counts)."""
-        plan = self._cfg.faults
-        if (plan is not None and plan.site == "update_fail"
-                and self._n_updates == plan.update_n):
-            # the preemption drill: die host-side before anything commits
-            # (the committed store, totals and counters are untouched --
-            # the caller restores from its last checkpoint and replays)
-            raise resilience.InjectedFault(
-                f"injected failure at update #{self._n_updates} "
-                f"(FaultPlan site='update_fail')")
-        if self._spill is None and self._cfg.spill == "always":
-            self._engage_spill()
-        if self._spill is not None:
-            return self._spill_update(reads)
-        try:
-            return self._incore_update(reads)
-        except resilience.CapacityExhausted as e:
-            if (self._cfg.spill != "auto"
-                    or e.cause != resilience.STORE_REHASH):
-                raise
-            # tier 3 (graceful degradation): the rehash ladder ran out of
-            # HBM -- export the committed store to disk bins and replay
-            # this batch out-of-core. The ladder's rounds seed the spill
-            # controllers' history, so later give-ups still show WHY the
-            # tier engaged.
-            self._rounds = list(e.rounds)
-            for cause, n in e.counts.items():
-                self._retries[cause] += n
-            self._engage_spill()
-            return self._spill_update(reads)
+        with TraceAnnotation("kc.update", batch=self._n_updates):
+            plan = self._cfg.faults
+            if (plan is not None and plan.site == "update_fail"
+                    and self._n_updates == plan.update_n):
+                # the preemption drill: die host-side before anything
+                # commits (the committed store, totals and counters are
+                # untouched -- the caller restores from its last
+                # checkpoint and replays)
+                raise resilience.InjectedFault(
+                    f"injected failure at update #{self._n_updates} "
+                    f"(FaultPlan site='update_fail')")
+            if self._spill is None and self._cfg.spill == "always":
+                self._engage_spill()
+            if self._spill is not None:
+                return self._spill_update(reads)
+            try:
+                return self._incore_update(reads)
+            except resilience.CapacityExhausted as e:
+                if (self._cfg.spill != "auto"
+                        or e.cause != resilience.STORE_REHASH):
+                    raise
+                # tier 3 (graceful degradation): the rehash ladder ran out
+                # of HBM -- export the committed store to disk bins and
+                # replay this batch out-of-core. The ladder's rounds seed
+                # the spill controllers' history, so later give-ups still
+                # show WHY the tier engaged.
+                self._rounds = list(e.rounds)
+                for cause, n in e.counts.items():
+                    self._retries[cause] += n
+                self._engage_spill()
+                return self._spill_update(reads)
 
     def _incore_update(self, reads: jax.Array) -> DAKCStats:
-        if self._skeys is None:
-            self._alloc(reads)
-        plan = self._cfg.faults
-        shape = tuple(reads.shape)
-        engaged = _hop2_engaged(self._cfg) and not self._hop2_padded
-        hop2_est = None
-        if engaged or _compact_engaged(self._cfg):
-            mode = _plan_caps(self._cfg, self._num_pes, shape,
-                              self._slack)[0]
-            hop2_est = _chunk_valid_estimate(reads, self._cfg, mode, shape,
-                                             self._num_pes)
-        ctrl = resilience.RetryController(
-            self._cfg.retry, slack=self._slack, store_cap=self._store_cap,
-            hop2_padded=not engaged, history=self._rounds)
+        batch = self._n_updates
+        with TraceAnnotation("kc.plan", batch=batch, round=0):
+            if self._skeys is None:
+                self._alloc(reads)
+            plan = self._cfg.faults
+            shape = tuple(reads.shape)
+            engaged = _hop2_engaged(self._cfg) and not self._hop2_padded
+            hop2_est = None
+            if engaged or _compact_engaged(self._cfg):
+                mode = _plan_caps(self._cfg, self._num_pes, shape,
+                                  self._slack)[0]
+                hop2_est = _chunk_valid_estimate(reads, self._cfg, mode,
+                                                 shape, self._num_pes)
+            ctrl = resilience.RetryController(
+                self._cfg.retry, slack=self._slack,
+                store_cap=self._store_cap, hop2_padded=not engaged,
+                history=self._rounds)
         while True:
+            rnd = ctrl.attempts
             if ctrl.store_cap != self._store_cap:
-                self._grow(ctrl.store_cap)   # rehash round; then replay
-            hop2_caps = _retry_hop2_caps(reads, self._cfg, self._num_pes,
-                                         shape, ctrl, hop2_est)
-            compact_caps = _resolve_compact(reads, self._cfg, self._num_pes,
-                                            shape, ctrl.slack, est=hop2_est)
-            fault = resilience.active_trace_fault(plan, ctrl.attempts)
-            fn = _update_executable(self._cfg, self._mesh, self._axes,
-                                    shape, str(reads.dtype), ctrl.slack,
-                                    self._store_cap, hop2_caps=hop2_caps,
-                                    compact_caps=compact_caps, fault=fault)
-            nk, nc, raw_stats = fn(reads, self._skeys, self._scounts)
-            stats = _host_stats(self._cfg, raw_stats)
-            if not ctrl.observe(route_dropped=int(stats.overflow),
-                                store_dropped=int(stats.store_overflow),
-                                hop2_dropped=int(stats.hop2_dropped)):
+                # rehash round; then replay
+                with TraceAnnotation("kc.grow", batch=batch, round=rnd):
+                    self._grow(ctrl.store_cap)
+            with TraceAnnotation("kc.plan", batch=batch, round=rnd):
+                hop2_caps = _retry_hop2_caps(reads, self._cfg,
+                                             self._num_pes, shape, ctrl,
+                                             hop2_est)
+                compact_caps = _resolve_compact(reads, self._cfg,
+                                                self._num_pes, shape,
+                                                ctrl.slack, est=hop2_est)
+                fault = resilience.active_trace_fault(plan, ctrl.attempts)
+                fn = _update_executable(
+                    self._cfg, self._mesh, self._axes, shape,
+                    str(reads.dtype), ctrl.slack, self._store_cap,
+                    hop2_caps=hop2_caps, compact_caps=compact_caps,
+                    fault=fault)
+            with TraceAnnotation("kc.run", batch=batch, round=rnd):
+                nk, nc, raw_stats = fn(reads, self._skeys, self._scounts)
+            # the host's first read of the stats waits for the device
+            with TraceAnnotation("kc.sync", batch=batch, round=rnd):
+                stats = _host_stats(self._cfg, raw_stats)
+                again = ctrl.observe(
+                    route_dropped=int(stats.overflow),
+                    store_dropped=int(stats.store_overflow),
+                    hop2_dropped=int(stats.hop2_dropped))
+            if not again:
                 break
-        self._skeys, self._scounts = nk, nc
-        # write the controller's final knobs back into the sticky state
-        # (doubled slack and the padded-hop-2 fallback persist for future
-        # batches; the grown store already committed via _grow)
-        self._slack = ctrl.slack
-        self._rounds = ctrl.rounds
-        if _hop2_engaged(self._cfg):
-            self._hop2_padded = ctrl.hop2_padded
-        for cause, n in ctrl.counts.items():
-            self._retries[cause] += n
-        self._n_updates += 1
-        self._raw += int(stats.raw_kmers)
-        self._sent += int(stats.sent_words)
-        self._wire_bytes += int(stats.wire_bytes)
-        batch_fill = np.asarray(raw_stats[7], dtype=np.int64)
-        self._fill = (batch_fill if self._fill is None
-                      else self._fill + batch_fill)
-        self._publish()
-        return _stamp_retries(stats, ctrl.counts)
+        with TraceAnnotation("kc.commit", batch=batch):
+            self._skeys, self._scounts = nk, nc
+            # write the controller's final knobs back into the sticky
+            # state (doubled slack and the padded-hop-2 fallback persist
+            # for future batches; the grown store already committed via
+            # _grow)
+            self._slack = ctrl.slack
+            self._rounds = ctrl.rounds
+            if _hop2_engaged(self._cfg):
+                self._hop2_padded = ctrl.hop2_padded
+            for cause, n in ctrl.counts.items():
+                self._retries[cause] += n
+            self._n_updates += 1
+            self._raw += int(stats.raw_kmers)
+            self._sent += int(stats.sent_words)
+            self._wire_bytes += int(stats.wire_bytes)
+            batch_fill = np.asarray(raw_stats[7], dtype=np.int64)
+            self._fill = (batch_fill if self._fill is None
+                          else self._fill + batch_fill)
+            self._publish()
+            return _stamp_retries(stats, ctrl.counts)
 
     # --- the spill tier (core/spill.py) --------------------------------------
 
@@ -2039,37 +2074,38 @@ class KmerCounter:
         than once; the store keeps accepting updates in between). With
         the spill tier engaged this is the DRAIN: per-bin fold + compact
         (`_drain_bins`), host-resident AccumResult, same layout."""
-        lmm, p99 = (_imbalance(self._fill) if self._fill is not None
-                    else (0.0, 0))
-        if self._spill is not None:
-            result, folded = self._drain_bins()
-            self._bins_folded = folded
+        with TraceAnnotation("kc.finalize"):
+            lmm, p99 = (_imbalance(self._fill) if self._fill is not None
+                        else (0.0, 0))
+            if self._spill is not None:
+                result, folded = self._drain_bins()
+                self._bins_folded = folded
+                stats = DAKCStats(
+                    overflow=np.int64(0), sent_words=np.int64(self._sent),
+                    wire_bytes=np.int64(self._wire_bytes),
+                    raw_kmers=np.int64(self._raw), num_global_syncs=3,
+                    store_overflow=np.int64(0),
+                    load_max_over_mean=lmm, owner_fill_p99=p99,
+                    spilled_bins=self._spill.spilled_bins,
+                    spilled_bytes=self._spill.spilled_bytes,
+                    bins_folded=folded)
+                return result, _stamp_retries(stats, self._retries)
+            if self._skeys is None:
+                raise RuntimeError("KmerCounter.finalize before any update")
+            fn = _finalize_executable(self._cfg, self._mesh, self._axes,
+                                      self._store_cap)
+            result = fn(self._skeys, self._scounts)
+            # int64 throughout: an unbounded stream's cumulative totals outgrow
+            # int32 long before anything else breaks. retry_* counters are the
+            # stream's LIFETIME totals (per-batch counts ride each update()'s
+            # returned stats).
             stats = DAKCStats(
                 overflow=np.int64(0), sent_words=np.int64(self._sent),
                 wire_bytes=np.int64(self._wire_bytes),
                 raw_kmers=np.int64(self._raw), num_global_syncs=3,
                 store_overflow=np.int64(0),
-                load_max_over_mean=lmm, owner_fill_p99=p99,
-                spilled_bins=self._spill.spilled_bins,
-                spilled_bytes=self._spill.spilled_bytes,
-                bins_folded=folded)
+                load_max_over_mean=lmm, owner_fill_p99=p99)
             return result, _stamp_retries(stats, self._retries)
-        if self._skeys is None:
-            raise RuntimeError("KmerCounter.finalize before any update")
-        fn = _finalize_executable(self._cfg, self._mesh, self._axes,
-                                  self._store_cap)
-        result = fn(self._skeys, self._scounts)
-        # int64 throughout: an unbounded stream's cumulative totals outgrow
-        # int32 long before anything else breaks. retry_* counters are the
-        # stream's LIFETIME totals (per-batch counts ride each update()'s
-        # returned stats).
-        stats = DAKCStats(
-            overflow=np.int64(0), sent_words=np.int64(self._sent),
-            wire_bytes=np.int64(self._wire_bytes),
-            raw_kmers=np.int64(self._raw), num_global_syncs=3,
-            store_overflow=np.int64(0),
-            load_max_over_mean=lmm, owner_fill_p99=p99)
-        return result, _stamp_retries(stats, self._retries)
 
     # --- the query path (core/query.py) --------------------------------------
 

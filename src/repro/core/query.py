@@ -77,6 +77,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import aggregation, compat, countstore, encoding, fabsp, spill
@@ -213,29 +214,33 @@ def _query_executable(cfg, mesh: Mesh, axis_names, dtype_name: str,
             pe = pe * mesh.shape[ax] + jax.lax.axis_index(ax)
         qid = (pe * n_local + jnp.arange(n_local, dtype=jnp.int32)
                + jnp.int32(1))           # 1-based: 0 marks tile padding
-        owners = owner_pe(fabsp._ownership_keys(qwords, cfg), num_pes)
-        rr = aggregation.route_lanes(
-            (qwords, qid), ("word", "i32"), owners, valid,
-            num_pes=num_pes, capacity=n_local, axis_names=axes, grid=grid,
-            impl=cfg.partition_impl, route2d="oneplan")
-        rwords, rqid = rr.lanes
-        rvalid = rwords != sent
-        counts, probes = ops.hash_lookup(
-            skeys, scounts, rwords, countstore.store_slots(rwords, store_cap),
-            sentinel_val=int(jnp.iinfo(qwords.dtype).max))
-        back = (rqid - jnp.int32(1)) // jnp.int32(n_local)
-        rr2 = aggregation.route_lanes(
-            (rqid, counts), ("i32", "i32"), back, rvalid,
-            num_pes=num_pes, capacity=n_local, axis_names=axes, grid=grid,
-            impl=cfg.partition_impl, route2d="oneplan")
-        bqid, bcounts = rr2.lanes
-        # qids are globally unique, so each live answer owns its slot; the
-        # padding slots (bqid == 0) scatter off the end and drop
-        dst = jnp.where(bqid > jnp.int32(0),
-                        (bqid - jnp.int32(1)) % jnp.int32(n_local),
-                        jnp.int32(n_local))
-        out = jnp.zeros((n_local,), jnp.int32).at[dst].add(bcounts,
-                                                           mode="drop")
+        with jax.named_scope("route"):
+            owners = owner_pe(fabsp._ownership_keys(qwords, cfg), num_pes)
+            rr = aggregation.route_lanes(
+                (qwords, qid), ("word", "i32"), owners, valid,
+                num_pes=num_pes, capacity=n_local, axis_names=axes,
+                grid=grid, impl=cfg.partition_impl, route2d="oneplan")
+            rwords, rqid = rr.lanes
+            rvalid = rwords != sent
+        with jax.named_scope("lookup"):
+            counts, probes = ops.hash_lookup(
+                skeys, scounts, rwords,
+                countstore.store_slots(rwords, store_cap),
+                sentinel_val=int(jnp.iinfo(qwords.dtype).max))
+        with jax.named_scope("route"):
+            back = (rqid - jnp.int32(1)) // jnp.int32(n_local)
+            rr2 = aggregation.route_lanes(
+                (rqid, counts), ("i32", "i32"), back, rvalid,
+                num_pes=num_pes, capacity=n_local, axis_names=axes,
+                grid=grid, impl=cfg.partition_impl, route2d="oneplan")
+            bqid, bcounts = rr2.lanes
+            # qids are globally unique, so each live answer owns its slot;
+            # the padding slots (bqid == 0) scatter off the end and drop
+            dst = jnp.where(bqid > jnp.int32(0),
+                            (bqid - jnp.int32(1)) % jnp.int32(n_local),
+                            jnp.int32(n_local))
+            out = jnp.zeros((n_local,), jnp.int32).at[dst].add(
+                bcounts, mode="drop")
         hits = ((counts > 0) & rvalid).sum().astype(jnp.int32)
         prb = jnp.where(rvalid, probes, 0)
         whi, wlo = fabsp._wire_add(jnp.int32(0), jnp.int32(0),
@@ -266,24 +271,30 @@ def query_counts(kmers, mesh: Mesh, cfg, skeys: jax.Array,
     axes = tuple(axis_names)
     num_pes = fabsp._mesh_pes(mesh, axes)
     store_cap = skeys.shape[0] // num_pes
-    words = pack_queries(kmers, cfg)
+    with TraceAnnotation("query.pack"):
+        words = pack_queries(kmers, cfg)
+        host_words = np.asarray(words)
     nq = int(words.shape[0])
     n_local = fabsp._pow2ceil(max(1, -(-nq // num_pes)))
     dt = words.dtype
     sent = int(jnp.iinfo(dt).max)
-    padded = np.full((num_pes * n_local,), sent, dtype=dt)
-    padded[:nq] = np.asarray(words)
-    sharding = NamedSharding(mesh, fabsp._data_spec(axes))
-    qdev = jax.device_put(jnp.asarray(padded), sharding)
-    fn = _query_executable(cfg, mesh, axes, str(np.dtype(dt)), n_local,
-                           store_cap)
-    out, (hits, whi, wlo, psum, pmax) = fn(qdev, skeys, scounts)
-    counts = np.asarray(out)[:nq]
-    stats = QueryStats(
-        n_queries=nq, n_hits=int(hits),
-        wire_bytes=(int(whi) << fabsp._WIRE_SHIFT) + int(wlo),
-        probe_sum=int(psum), probe_max=int(pmax), n_local=n_local,
-        batch_fill=nq / (n_local * num_pes))
+    with TraceAnnotation("query.put"):
+        padded = np.full((num_pes * n_local,), sent, dtype=dt)
+        padded[:nq] = host_words
+        sharding = NamedSharding(mesh, fabsp._data_spec(axes))
+        qdev = jax.device_put(jnp.asarray(padded), sharding)
+    with TraceAnnotation("query.run", n_local=n_local):
+        fn = _query_executable(cfg, mesh, axes, str(np.dtype(dt)), n_local,
+                               store_cap)
+        out, (hits, whi, wlo, psum, pmax) = fn(qdev, skeys, scounts)
+    # the first read of the answers waits for the lookup
+    with TraceAnnotation("query.fetch"):
+        counts = np.asarray(out)[:nq]
+        stats = QueryStats(
+            n_queries=nq, n_hits=int(hits),
+            wire_bytes=(int(whi) << fabsp._WIRE_SHIFT) + int(wlo),
+            probe_sum=int(psum), probe_max=int(pmax), n_local=n_local,
+            batch_fill=nq / (n_local * num_pes))
     return counts, stats
 
 

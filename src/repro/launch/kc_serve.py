@@ -138,42 +138,49 @@ class QueryService:
         requests short-circuit with an empty answer and zeroed stats, no
         device round-trip; the coalesced batch carries the tenant's own
         packed-word dtype (`_batch_dtype`), never a hardcoded uint32."""
+        from jax.profiler import TraceAnnotation
+
         from repro.core import query as query_lib
         pending, self._pending = self._pending, []
-        by_tenant: Dict[str, List[int]] = {}
-        for i, (tenant, _) in enumerate(pending):
-            by_tenant.setdefault(tenant, []).append(i)
-        results: List[object] = [None] * len(pending)
-        for tenant, idxs in by_tenant.items():
-            try:
-                counter = self._registry.get(tenant)
-                for i in idxs:
-                    if len(pending[i][1]) == 0:
-                        results[i] = (np.zeros((0,), np.int32),
-                                      self._zero_stats(tenant))
-                live = [i for i in idxs if len(pending[i][1])]
-                if not live:
+        with TraceAnnotation("serve.flush", requests=len(pending),
+                             queries=sum(len(q) for _, q in pending)):
+            by_tenant: Dict[str, List[int]] = {}
+            for i, (tenant, _) in enumerate(pending):
+                by_tenant.setdefault(tenant, []).append(i)
+            results: List[object] = [None] * len(pending)
+            for tenant, idxs in by_tenant.items():
+                try:
+                    with TraceAnnotation("serve.coalesce"):
+                        counter = self._registry.get(tenant)
+                        for i in idxs:
+                            if len(pending[i][1]) == 0:
+                                results[i] = (np.zeros((0,), np.int32),
+                                              self._zero_stats(tenant))
+                        live = [i for i in idxs if len(pending[i][1])]
+                        if not live:
+                            continue
+                        dt_word = self._batch_dtype(counter)
+                        batch = np.concatenate(
+                            [pending[i][1] if pending[i][1].ndim != 1
+                             else pending[i][1].astype(dt_word, copy=False)
+                             for i in live])
+                    t0 = time.perf_counter()
+                    counts = counter.count(batch)
+                    dt = time.perf_counter() - t0
+                except (query_lib.QueryUnavailable, UnknownStore) as e:
+                    for i in idxs:
+                        results[i] = e
                     continue
-                dt_word = self._batch_dtype(counter)
-                batch = np.concatenate(
-                    [pending[i][1] if pending[i][1].ndim != 1
-                     else pending[i][1].astype(dt_word, copy=False)
-                     for i in live])
-                t0 = time.perf_counter()
-                counts = counter.count(batch)
-                dt = time.perf_counter() - t0
-            except (query_lib.QueryUnavailable, UnknownStore) as e:
-                for i in idxs:
-                    results[i] = e
-                continue
-            qs = counter.last_query_stats
-            off = 0
-            for i in live:
-                n = len(pending[i][1])
-                part = counts[off:off + n]
-                off += n
-                results[i] = (part, self._request_stats(
-                    tenant, qs, n, dt, n_hits=int((part > 0).sum())))
+                with TraceAnnotation("serve.split"):
+                    qs = counter.last_query_stats
+                    off = 0
+                    for i in live:
+                        n = len(pending[i][1])
+                        part = counts[off:off + n]
+                        off += n
+                        results[i] = (part, self._request_stats(
+                            tenant, qs, n, dt,
+                            n_hits=int((part > 0).sum())))
         return results
 
     @staticmethod
