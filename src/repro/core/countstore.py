@@ -42,7 +42,7 @@ hash(x) == p (mod P), and reusing that hash for slots would populate only
 
 Backend note: `impl='auto'` runs the Pallas kernel on TPU and the
 bit-identical jnp oracle elsewhere (ops.hash_insert -- interpret-mode
-emulation of the scalar probe loop costs O(capacity) per store, so it is
+emulation of the probe loop costs O(capacity) per store, so it is
 reserved for the kernel parity tests).
 
 Query/serving contract (`store_lookup`, core/query.py): the committed
@@ -135,15 +135,24 @@ def store_insert(store: CountStore, words: jax.Array,
                  impl: str = "auto") -> CountStore:
     """Fold (words, counts) into the store; sentinel / zero-count entries
     are skipped. Returns the updated store with `dropped` accumulated."""
+    return store_insert_counted(store, words, counts, impl=impl)[0]
+
+
+def store_insert_counted(store: CountStore, words: jax.Array,
+                         counts: Optional[jax.Array] = None, *,
+                         impl: str = "auto"):
+    """`store_insert`, plus the insert's (2,) int32 group counts (groups
+    with a live item, groups that fell back to the one-item walk:
+    `ops.hash_insert_counted`). Returns (store, groups)."""
     sent = jnp.iinfo(words.dtype).max
     if counts is None:
         counts = (words != words.dtype.type(sent)).astype(jnp.int32)
     capacity = store.keys.shape[0]
-    keys, cnts, dropped = ops.hash_insert(
+    keys, cnts, dropped, groups = ops.hash_insert_counted(
         store.keys, store.counts, words, counts,
         store_slots(words, capacity), sentinel_val=int(sent), impl=impl)
     return CountStore(keys=keys, counts=cnts,
-                      dropped=store.dropped + dropped)
+                      dropped=store.dropped + dropped), groups
 
 
 def store_lookup(store: CountStore, words: jax.Array, *,

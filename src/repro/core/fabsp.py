@@ -442,14 +442,22 @@ class DAKCStats(NamedTuple):
     spilled_bins: int = 0
     spilled_bytes: int = 0
     bins_folded: int = 0
+    # The receiver insert's grouped path (kernels/hash_table.py): groups
+    # of consecutive inserted items that held a live item, and those of
+    # them that fell back to the one-item walk (a shared home row, or an
+    # item whose home row from its home lane on was full). () int32,
+    # psum'd; 0 for tables too small to group and off the stream receiver.
+    insert_groups: jax.Array = 0
+    insert_fallbacks: jax.Array = 0
 
 
 # Flat per-call stats tuple threaded out of the shard_map body, in order:
 # (route_overflow, store_overflow, sent_words, wire_hi, wire_lo, raw_kmers,
-#  hop2_dropped, fill). All scalars except `fill`, the (num_pes,) int32
-# hop-1 per-destination fill histogram (psum'd like the rest; consumers
-# that index the tuple numerically must special-case index 7).
-STATS_FIELDS = 8
+#  hop2_dropped, fill, insert_groups, insert_fallbacks). All scalars except
+# `fill`, the (num_pes,) int32 hop-1 per-destination fill histogram
+# (psum'd like the rest; consumers that index the tuple numerically must
+# special-case index 7).
+STATS_FIELDS = 10
 
 
 def _imbalance(fill) -> Tuple[float, int]:
@@ -754,45 +762,48 @@ def _stream_fold(chunks, store: countstore.CountStore, *, cfg: DAKCConfig,
     full table.
 
     Returns (store, (raw, sent_words, wire_hi, wire_lo, route_overflow,
-    hop2_dropped, fill)). The scan emits NO per-chunk outputs -- receive
-    memory is the store plus one in-flight tile, independent of the chunk
-    count.
+    hop2_dropped, fill, insert_groups, insert_fallbacks)). The scan emits
+    NO per-chunk outputs -- receive memory is the store plus one in-flight
+    tile, independent of the chunk count.
     """
 
     def step(carry, xs):
         chunk, cidx = xs
-        raw_t, sent_t, whi, wlo, ovf_t, h2_t, fill_t, st = carry
+        raw_t, sent_t, whi, wlo, ovf_t, h2_t, fill_t, grp_t, st = carry
         recv, (raw, sent_w, wire, ovf, h2, fl) = _phase1_step(
             chunk, cfg=cfg, num_pes=num_pes, cap_n=cap_n, cap_h=cap_h,
             mode=mode, axis_names=axis_names, grid=grid, hop2_caps=hop2_caps,
             compact_caps=compact_caps, chunk_idx=cidx, fault=fault)
         with jax.named_scope("insert"):
-            st = _fold_recv(recv, st, cfg=cfg, mode=mode, fault=fault,
-                            cidx=cidx)
+            st, groups = _fold_recv(recv, st, cfg=cfg, mode=mode,
+                                    fault=fault, cidx=cidx)
         whi, wlo = _wire_add(whi, wlo, wire)
         # explicit int32: x64 mode (k=31 words) promotes reductions to int64
         return (raw_t + raw.astype(jnp.int32),
                 sent_t + sent_w.astype(jnp.int32), whi, wlo,
                 ovf_t + ovf.astype(jnp.int32),
                 h2_t + h2.astype(jnp.int32),
-                fill_t + fl.astype(jnp.int32), st), None
+                fill_t + fl.astype(jnp.int32), grp_t + groups, st), None
 
     zero = jnp.int32(0)
     zfill = jnp.zeros((num_pes,), jnp.int32)
     chunk_ids = jnp.arange(chunks.shape[0], dtype=jnp.int32)
-    (raw, sent_w, whi, wlo, ovf, h2, fill, store), _ = jax.lax.scan(
-        step, (zero, zero, zero, zero, zero, zero, zfill, store),
+    (raw, sent_w, whi, wlo, ovf, h2, fill, groups, store), _ = jax.lax.scan(
+        step, (zero, zero, zero, zero, zero, zero, zfill,
+               jnp.zeros((2,), jnp.int32), store),
         (chunks, chunk_ids))
-    return store, (raw, sent_w, whi, wlo, ovf, h2, fill)
+    return store, (raw, sent_w, whi, wlo, ovf, h2, fill, groups[0],
+                   groups[1])
 
 
 def _fold_recv(recv, st: countstore.CountStore, *, cfg: DAKCConfig,
-               mode: str, fault, cidx) -> countstore.CountStore:
+               mode: str, fault, cidx):
     """Decode one step's received tiles and insert them into the store
-    (the `insert` layer of `_stream_fold`'s scan step)."""
+    (the `insert` layer of `_stream_fold`'s scan step). Returns (store,
+    the insert's (2,) group counts)."""
     kmers, cnts = _recv_pairs(recv, cfg=cfg, mode=mode)
     if fault is None or fault.site != "store_drop":
-        return countstore.store_insert(st, kmers, cnts)
+        return countstore.store_insert_counted(st, kmers, cnts)
     hit = resilience.fault_mask(kmers.shape[0], fault, cidx)
     if fault.fill > 0:
         sent_k = jnp.array(jnp.iinfo(st.keys.dtype).max, st.keys.dtype)
@@ -800,8 +811,10 @@ def _fold_recv(recv, st: countstore.CountStore, *, cfg: DAKCConfig,
         hit = hit & (occupied.astype(jnp.float32)
                      >= fault.fill * st.keys.shape[0])
     drop = hit & (cnts > 0)
-    st = countstore.store_insert(st, kmers, jnp.where(drop, 0, cnts))
-    return st._replace(dropped=st.dropped + jnp.sum(drop).astype(jnp.int32))
+    st, groups = countstore.store_insert_counted(st, kmers,
+                                                 jnp.where(drop, 0, cnts))
+    return st._replace(
+        dropped=st.dropped + jnp.sum(drop).astype(jnp.int32)), groups
 
 
 def _chunked(reads_local: jax.Array, chunk_reads: int) -> jax.Array:
@@ -821,7 +834,8 @@ def _local_count(reads_local: jax.Array, *, cfg: DAKCConfig, num_pes: int,
     if cfg.receiver_impl == "stream":
         dt = encoding.kmer_dtype(cfg.k, cfg.bits_per_symbol)
         store = countstore.empty_store(store_cap, dt)
-        store, (raw, sent_w, whi, wlo, ovf, h2, fill) = _stream_fold(
+        store, (raw, sent_w, whi, wlo, ovf, h2, fill, grp,
+                fb) = _stream_fold(
             chunks, store, cfg=cfg, num_pes=num_pes, cap_n=cap_n,
             cap_h=cap_h, mode=mode, axis_names=axis_names, grid=grid,
             hop2_caps=hop2_caps, compact_caps=compact_caps, fault=fault)
@@ -852,11 +866,12 @@ def _local_count(reads_local: jax.Array, *, cfg: DAKCConfig, num_pes: int,
             (chunks, jnp.arange(chunks.shape[0], dtype=jnp.int32)))
         recv_n, recv_h, recv_hc = recvs
         result = _phase2(recv_n, recv_h, recv_hc, cfg=cfg, mode=mode)
-        store_ovf = jnp.int32(0)
+        store_ovf = grp = fb = jnp.int32(0)
 
     ax = tuple(axis_names)
     stats = tuple(jax.lax.psum(x, ax)
-                  for x in (ovf, store_ovf, sent_w, whi, wlo, raw, h2, fill))
+                  for x in (ovf, store_ovf, sent_w, whi, wlo, raw, h2, fill,
+                            grp, fb))
     return AccumResult(unique=result.unique, counts=result.counts,
                        num_unique=result.num_unique.reshape(1)), stats
 
@@ -1260,7 +1275,7 @@ def _counting_executable(cfg: DAKCConfig, mesh: Mesh, axis_names, shape,
 
 def _host_stats(cfg: DAKCConfig, raw_stats) -> DAKCStats:
     (route_ovf, store_ovf, sent_w, whi, wlo, raw, hop2_dropped,
-     fill) = raw_stats
+     fill, groups, fallbacks) = raw_stats
     # the traced accumulator already counts bytes (see _wire_add)
     wire_bytes = (int(whi) << _WIRE_SHIFT) + int(wlo)
     lmm, p99 = _imbalance(fill)
@@ -1268,7 +1283,8 @@ def _host_stats(cfg: DAKCConfig, raw_stats) -> DAKCStats:
                      wire_bytes=np.int64(wire_bytes),
                      raw_kmers=raw, num_global_syncs=3,
                      store_overflow=store_ovf, hop2_dropped=hop2_dropped,
-                     load_max_over_mean=lmm, owner_fill_p99=p99)
+                     load_max_over_mean=lmm, owner_fill_p99=p99,
+                     insert_groups=groups, insert_fallbacks=fallbacks)
 
 
 def _retry_hop2_caps(reads, cfg: DAKCConfig, num_pes: int, shape,
@@ -1393,14 +1409,15 @@ def _update_executable(cfg: DAKCConfig, mesh: Mesh, axis_names, shape,
         chunks = _chunked(reads_local, cfg.chunk_reads)
         store = countstore.CountStore(keys=skeys, counts=scounts,
                                       dropped=jnp.int32(0))
-        store, (raw, sent_w, whi, wlo, ovf, h2, fill) = _stream_fold(
+        store, (raw, sent_w, whi, wlo, ovf, h2, fill, grp,
+                fb) = _stream_fold(
             chunks, store, cfg=cfg, num_pes=num_pes, cap_n=cap_n,
             cap_h=cap_h, mode=mode, axis_names=axis_names, grid=grid,
             hop2_caps=hop2_caps, compact_caps=compact_caps, fault=fault)
         ax = tuple(axis_names)
         stats = tuple(jax.lax.psum(x, ax)
                       for x in (ovf, store.dropped, sent_w, whi, wlo, raw,
-                                h2, fill))
+                                h2, fill, grp, fb))
         return store.keys, store.counts, stats
 
     fn = jax.jit(compat.shard_map(
@@ -1574,7 +1591,8 @@ def _spill_route_executable(cfg: DAKCConfig, mesh: Mesh, axis_names, shape,
                       for x in (ovf.astype(jnp.int32), jnp.int32(0),
                                 sent_w.astype(jnp.int32), whi, wlo,
                                 raw.astype(jnp.int32), h2.astype(jnp.int32),
-                                fl.astype(jnp.int32)))
+                                fl.astype(jnp.int32), jnp.int32(0),
+                                jnp.int32(0)))
         return lanes, stats
 
     fn = jax.jit(compat.shard_map(
@@ -1842,7 +1860,9 @@ class KmerCounter:
                     hop2_dropped=int(stats.hop2_dropped))
             if not again:
                 break
-        with TraceAnnotation("kc.commit", batch=batch):
+        with TraceAnnotation("kc.commit", batch=batch,
+                             insert_groups=int(stats.insert_groups),
+                             insert_fallbacks=int(stats.insert_fallbacks)):
             self._skeys, self._scounts = nk, nc
             # write the controller's final knobs back into the sticky
             # state (doubled slack and the padded-hop-2 fallback persist

@@ -26,18 +26,30 @@ static-shape world:
   dropped and counted, and the caller's overflow round grows the capacity
   (the same discipline the routing tiles use). A landing writes its row
   back through a one-lane masked select.
-- Dropped-item counts accumulate in an SMEM carry across grid steps
-  (sequential grid => exact, as in segment_count.py).
+- Items are resolved `GROUP` (8, the sublane count) at a time where the
+  table has `_GROUP_MIN_ROWS` rows of 128 lanes or more (`insert_group`;
+  tiny test tables keep the one-item walk). A group with no live item
+  costs its scalar reads. Otherwise, if no two live items share a home
+  row (scalar compares of the SMEM slots), each live item's home row is
+  loaded and its landing lane found on the vector side, and one
+  vector-to-scalar move asks whether every live item landed in its home
+  row. When both hold, no item reads a row another writes, so storing
+  each row once (masked to the live items) is the stream-order fold.
+  Either condition failing -- a shared row, a home row full from the home
+  lane on, a wrap, a full table -- folds the group one item at a time by
+  the walk above, in stream order.
+- Dropped items, groups with a live item and groups that fell back
+  accumulate in an SMEM carry across grid steps (sequential grid =>
+  exact, as in segment_count.py).
 
-Determinism: tiles execute in order and items within a tile fold in input
-order, and the row walk lands on exactly the slot a one-slot-at-a-time
-linear probe would, so the final table state is bit-identical to the
-sequential pure-jnp oracle (`ref.hash_insert_ref`) -- slot layout included,
-not just the key->count multiset. The lookup kernel's probe counts are the
-oracle's step counts, recovered from the landing slot's distance to home.
-
-The item loop is still sequential (each insert may change the next probe);
-vectorized probe rounds are ROADMAP Speed item 2.
+Determinism: tiles execute in order, items within a tile fold in input
+order (a group's fast path is that order's result), and the row walk lands
+on exactly the slot a one-slot-at-a-time linear probe would, so the final
+table state is bit-identical to the sequential pure-jnp oracle
+(`ref.hash_insert_ref`, which also counts the groups) -- slot layout
+included, not just the key->count multiset. The lookup kernel's probe
+counts are the oracle's step counts, recovered from the landing slot's
+distance to home.
 """
 
 from __future__ import annotations
@@ -56,6 +68,11 @@ _LANES = 128
 # (tests/test_tpu_compile.py) admits 2^24 slots and refuses 2^25.
 _VMEM_BYTES = 128 * 1024 * 1024
 _VMEM_HEADROOM = 8 * 1024 * 1024
+# Items the insert resolves per home-row pass (the sublane count), and the
+# fewest table rows that take that path: in a smaller table most groups
+# would share a row and fall back.
+GROUP = 8
+_GROUP_MIN_ROWS = 4 * GROUP
 
 
 def max_vmem_capacity() -> int:
@@ -121,40 +138,139 @@ def _walk(tk, key, slot0, sent, active, rows: int, width: int):
     return found
 
 
+def insert_group(cap: int, tile: int) -> int:
+    """Items the insert resolves per home-row pass for a `cap`-slot table
+    fed in `tile`-item blocks: `GROUP` where the table has
+    `_GROUP_MIN_ROWS` rows of 128 lanes or more and the tile divides
+    into groups, else 0 (tiny tables keep the one-item loop)."""
+    rows, width = _layout(cap)
+    if width == _LANES and rows >= _GROUP_MIN_ROWS and tile % GROUP == 0:
+        return GROUP
+    return 0
+
+
+def _fold_one(tk, tc, keys_ref, w_ref, slots_ref, i, *, sent, rows: int,
+              width: int):
+    """Fold item `i` in by the sequential walk; 1 if it was dropped."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    key = keys_ref[i]
+    w = w_ref[i]
+    valid = (key != sent) & (w > 0)
+    found = _walk(tk, key, slots_ref[i], sent, valid, rows, width)
+    land = valid & (found >= 0)
+
+    @pl.when(land)
+    def _write():
+        row = jax.lax.div(found, jnp.int32(width))
+        mine = lane == jax.lax.rem(found, jnp.int32(width))
+        cur = tk[pl.ds(row, 1), :]
+        tk[pl.ds(row, 1), :] = jnp.where(mine & (cur == sent), key, cur)
+        tc[pl.ds(row, 1), :] = tc[pl.ds(row, 1), :] + jnp.where(
+            mine, w, jnp.int32(0))
+
+    return jnp.where(valid & (found < 0), jnp.int32(1), jnp.int32(0))
+
+
+def _fold_group(tk, tc, keys_ref, w_ref, slots_ref, base, *, sent,
+                rows: int, width: int, group: int):
+    """Fold items [base, base + group) in; returns (dropped, live, fell
+    back) as int32 scalars.
+
+    Fast path: every live item's first probe visit (its home row from the
+    home lane on) holds the sentinel or the key, and no two live items
+    share a home row. Then no item reads a row another one writes, so
+    landing all of them against the rows as loaded, and storing each row
+    once, is the stream-order fold. Otherwise the group folds one item at
+    a time (`_fold_one`)."""
+    one = functools.partial(_fold_one, tk, tc, keys_ref, w_ref, slots_ref,
+                            sent=sent, rows=rows, width=width)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    ids = [base + j for j in range(group)]
+    keys = [keys_ref[i] for i in ids]
+    zero = jnp.int32(0)
+
+    def sequential():
+        return jax.lax.fori_loop(base, base + group,
+                                 lambda i, d: d + one(i), zero), jnp.int32(1)
+
+    def resolve():
+        # a group of weight-0 items lands trivially and stores nothing
+        ws = [w_ref[i] for i in ids]
+        live = [(k != sent) & (w > 0) for k, w in zip(keys, ws)]
+        slots = [slots_ref[i] for i in ids]
+        home = [jax.lax.div(s, jnp.int32(width)) for s in slots]
+        # scalar: two live items in one row cannot both take the fast path
+        clash = functools.reduce(jnp.logical_or, [
+            live[a] & live[b] & (home[a] == home[b])
+            for a in range(group) for b in range(a + 1, group)])
+
+        def vector():
+            cur = [tk[pl.ds(r, 1), :] for r in home]
+            cnt = [tc[pl.ds(r, 1), :] for r in home]
+            first, worst = [], None
+            for j in range(group):
+                l0 = jax.lax.rem(slots[j], jnp.int32(width))
+                hit = (lane >= l0) & ((cur[j] == sent) | (cur[j] == keys[j]))
+                f = jnp.min(jnp.where(hit, lane, width), axis=1,
+                            keepdims=True)
+                first.append(f)
+                f = jnp.where(live[j], f, zero)
+                worst = f if worst is None else jnp.maximum(worst, f)
+            landed = jnp.max(worst) < width   # the group's one vector read
+
+            def commit():
+                for j in range(group):
+                    mine = lane == first[j]
+                    keep = jnp.full((1, width), live[j])
+                    pltpu.store(tk.at[pl.ds(home[j], 1), :],
+                                jnp.where(mine & (cur[j] == sent), keys[j],
+                                          cur[j]), mask=keep)
+                    pltpu.store(tc.at[pl.ds(home[j], 1), :],
+                                cnt[j] + jnp.where(mine, ws[j], zero),
+                                mask=keep)
+                return zero, zero
+
+            return jax.lax.cond(landed, commit, sequential)
+
+        dropped, fell = jax.lax.cond(clash, sequential, vector)
+        any_live = functools.reduce(jnp.logical_or, live)
+        return dropped, any_live.astype(jnp.int32), fell
+
+    # padding groups cost their key reads
+    any_key = functools.reduce(jnp.logical_or, [k != sent for k in keys])
+    return jax.lax.cond(any_key, resolve, lambda: (zero, zero, zero))
+
+
 def _hash_insert_kernel(keys_ref, w_ref, slots_ref, tk_hbm, tc_hbm,
-                        ok_hbm, oc_hbm, ovf_ref, tk, tc, carry, *,
-                        sentinel_val: int, rows: int, width: int):
+                        ok_hbm, oc_hbm, stat_ref, tk, tc, carry, *,
+                        sentinel_val: int, rows: int, width: int,
+                        group: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         pltpu.sync_copy(tk_hbm, tk)
         pltpu.sync_copy(tc_hbm, tc)
-        carry[0] = jnp.int32(0)
+        for j in range(3):
+            carry[j] = jnp.int32(0)
 
     sent = jnp.int32(sentinel_val)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    n = keys_ref.shape[0]
+    zero = jnp.int32(0)
+    if group:
+        def step(g, acc):
+            out = _fold_group(tk, tc, keys_ref, w_ref, slots_ref, g * group,
+                              sent=sent, rows=rows, width=width, group=group)
+            return tuple(a + b for a, b in zip(acc, out))
 
-    def fold_one(i, dropped):
-        key = keys_ref[i]
-        w = w_ref[i]
-        valid = (key != sent) & (w > 0)
-        found = _walk(tk, key, slots_ref[i], sent, valid, rows, width)
-        land = valid & (found >= 0)
-
-        @pl.when(land)
-        def _write():
-            row = jax.lax.div(found, jnp.int32(width))
-            mine = lane == jax.lax.rem(found, jnp.int32(width))
-            cur = tk[pl.ds(row, 1), :]
-            tk[pl.ds(row, 1), :] = jnp.where(mine & (cur == sent), key, cur)
-            tc[pl.ds(row, 1), :] = tc[pl.ds(row, 1), :] + jnp.where(
-                mine, w, jnp.int32(0))
-
-        return dropped + jnp.where(valid & (found < 0), jnp.int32(1),
-                                   jnp.int32(0))
-
-    carry[0] = carry[0] + jax.lax.fori_loop(0, keys_ref.shape[0], fold_one,
-                                            jnp.int32(0))
-    ovf_ref[0] = carry[0]
+        acc = jax.lax.fori_loop(0, n // group, step, (zero, zero, zero))
+    else:
+        dropped = jax.lax.fori_loop(
+            0, n, lambda i, d: d + _fold_one(
+                tk, tc, keys_ref, w_ref, slots_ref, i, sent=sent, rows=rows,
+                width=width), zero)
+        acc = (dropped, zero, zero)
+    for j in range(3):
+        carry[j] = carry[j] + acc[j]
+        stat_ref[j] = carry[j]
 
     @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
     def _writeback():
@@ -180,10 +296,13 @@ def hash_insert_pallas(table_keys: jax.Array, table_counts: jax.Array,
     slots:   (n,) int32 home slots in [0, cap) -- hash(key) % cap, computed
              by the caller (core/countstore.py)
 
-    Returns (new_keys, new_counts, dropped): the updated table plus the
-    number of live entries dropped because a full probe sweep found neither
-    an empty nor a matching slot (table full => caller rehashes at doubled
-    capacity). n must divide by `tile`.
+    Returns (new_keys, new_counts, dropped, groups): the updated table,
+    the number of live entries dropped because a full probe sweep found
+    neither an empty nor a matching slot (table full => caller rehashes at
+    doubled capacity), and (2,) int32 group counts -- groups of
+    `insert_group(cap, tile)` items that held a live item, and those of
+    them that fell back to the one-item walk (both 0 where the table is
+    too small to group). n must divide by `tile`.
     """
     n = keys.shape[0]
     if n % tile != 0:
@@ -193,9 +312,9 @@ def hash_insert_pallas(table_keys: jax.Array, table_counts: jax.Array,
     word = table_keys.dtype
     sent = _sent_i32(sentinel_val, word)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    new_keys, new_counts, ovf = pl.pallas_call(
+    new_keys, new_counts, stat = pl.pallas_call(
         functools.partial(_hash_insert_kernel, sentinel_val=sent, rows=rows,
-                          width=width),
+                          width=width, group=insert_group(cap, tile)),
         grid=(n // tile,),
         in_specs=[_smem_tile(tile), _smem_tile(tile), _smem_tile(tile),
                   any_spec, any_spec],
@@ -203,17 +322,18 @@ def hash_insert_pallas(table_keys: jax.Array, table_counts: jax.Array,
                    pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=[jax.ShapeDtypeStruct((rows, width), jnp.int32),
                    jax.ShapeDtypeStruct((rows, width), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)],
+                   jax.ShapeDtypeStruct((3,), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((rows, width), jnp.int32),
                         pltpu.VMEM((rows, width), jnp.int32),
-                        pltpu.SMEM((1,), jnp.int32)],
+                        pltpu.SMEM((3,), jnp.int32)],
         compiler_params=_compiler_params(cap),
         interpret=interpret,
+        name="hash_insert",
     )(_as_i32(keys), weights.astype(jnp.int32), slots.astype(jnp.int32),
       _as_i32(table_keys).reshape(rows, width),
       table_counts.astype(jnp.int32).reshape(rows, width))
     new_keys = jax.lax.bitcast_convert_type(new_keys.reshape(cap), word)
-    return new_keys, new_counts.reshape(cap), ovf[0]
+    return new_keys, new_counts.reshape(cap), stat[0], stat[1:]
 
 
 def _hash_lookup_kernel(keys_ref, slots_ref, tk_hbm, tc_hbm,

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.hash_table import hash_insert_pallas, hash_lookup_pallas
+from repro.kernels.hash_table import (hash_insert_pallas, hash_lookup_pallas,
+                                      insert_group)
 from repro.kernels.kmer_extract import kmer_extract_pallas
 from repro.kernels.minimizer import (sliding_min_pallas,
                                      sliding_min_pair_pallas)
@@ -117,6 +118,31 @@ def segment_accumulate(sorted_keys: jax.Array, weights: jax.Array, *,
                                      interpret=_interpret_for(sorted_keys))
 
 
+def _hash_insert(table_keys, table_counts, keys, weights, slots, *,
+                 sentinel_val: int, tile: int, impl: str):
+    n = keys.shape[0]
+    tile = min(tile, max(8, n))
+    pad = (-n) % tile
+    if pad:
+        sent = jnp.full((pad,), sentinel_val, keys.dtype)
+        keys = jnp.concatenate([keys, sent])
+        weights = jnp.concatenate([weights.astype(jnp.int32),
+                                   jnp.zeros((pad,), jnp.int32)])
+        slots = jnp.concatenate([slots.astype(jnp.int32),
+                                 jnp.zeros((pad,), jnp.int32)])
+    if impl == "auto":
+        impl = "ref" if _interpret_for(table_keys) else "pallas"
+    if impl == "ref":
+        return ref.hash_insert_ref(
+            table_keys, table_counts, keys, weights, slots, sentinel_val,
+            group=insert_group(table_keys.shape[0], tile))
+    if impl != "pallas":
+        raise ValueError(f"unknown hash_insert impl {impl!r}")
+    return hash_insert_pallas(table_keys, table_counts, keys, weights, slots,
+                              sentinel_val, tile=tile,
+                              interpret=_interpret_for(table_keys))
+
+
 @functools.partial(jax.jit, static_argnames=("sentinel_val", "tile", "impl"))
 def hash_insert(table_keys: jax.Array, table_counts: jax.Array,
                 keys: jax.Array, weights: jax.Array, slots: jax.Array, *,
@@ -133,26 +159,22 @@ def hash_insert(table_keys: jax.Array, table_counts: jax.Array,
     rather than the CPU default. On the TPU, 'auto' never picks the
     oracle: a 64-bit table raises `WideWordUnsupported`.
     """
-    n = keys.shape[0]
-    tile = min(tile, max(8, n))
-    pad = (-n) % tile
-    if pad:
-        sent = jnp.full((pad,), sentinel_val, keys.dtype)
-        keys = jnp.concatenate([keys, sent])
-        weights = jnp.concatenate([weights.astype(jnp.int32),
-                                   jnp.zeros((pad,), jnp.int32)])
-        slots = jnp.concatenate([slots.astype(jnp.int32),
-                                 jnp.zeros((pad,), jnp.int32)])
-    if impl == "auto":
-        impl = "ref" if _interpret_for(table_keys) else "pallas"
-    if impl == "ref":
-        return ref.hash_insert_ref(table_keys, table_counts, keys, weights,
-                                   slots, sentinel_val)
-    if impl != "pallas":
-        raise ValueError(f"unknown hash_insert impl {impl!r}")
-    return hash_insert_pallas(table_keys, table_counts, keys, weights, slots,
-                              sentinel_val, tile=tile,
-                              interpret=_interpret_for(table_keys))
+    return _hash_insert(table_keys, table_counts, keys, weights, slots,
+                        sentinel_val=sentinel_val, tile=tile, impl=impl)[:3]
+
+
+@functools.partial(jax.jit, static_argnames=("sentinel_val", "tile", "impl"))
+def hash_insert_counted(table_keys: jax.Array, table_counts: jax.Array,
+                        keys: jax.Array, weights: jax.Array,
+                        slots: jax.Array, *, sentinel_val: int,
+                        tile: int = 1024, impl: str = "auto"):
+    """`hash_insert` plus the insert's (2,) int32 group counts: groups of
+    consecutive items that held a live item, and those of them that fell
+    back to the one-item walk (`hash_table.insert_group`; both 0 for a
+    table too small to group). Returns (new_keys, new_counts, dropped,
+    groups)."""
+    return _hash_insert(table_keys, table_counts, keys, weights, slots,
+                        sentinel_val=sentinel_val, tile=tile, impl=impl)
 
 
 @functools.partial(jax.jit, static_argnames=("sentinel_val", "tile", "impl"))

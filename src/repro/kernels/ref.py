@@ -181,7 +181,7 @@ def segment_accumulate_ref(sorted_keys: jax.Array, weights: jax.Array,
 
 def hash_insert_ref(table_keys: jax.Array, table_counts: jax.Array,
                     keys: jax.Array, weights: jax.Array, slots: jax.Array,
-                    sentinel_val: int):
+                    sentinel_val: int, group: int = 0):
     """Sequential insert-or-add oracle: fold the batch in stream order.
 
     Linear probing from `slots[i]` wrapping modulo capacity: first empty
@@ -189,7 +189,15 @@ def hash_insert_ref(table_keys: jax.Array, table_counts: jax.Array,
     slot drops the item and counts it. Semantic ground truth for
     `hash_insert_pallas` -- the final table state must match bit-for-bit
     (slot layout included, since both fold in stream order).
-    Returns (new_keys, new_counts, dropped).
+
+    `group` > 0 also counts what the kernel's grouped path does with each
+    run of `group` consecutive items (the batch length a multiple of it):
+    a group with a live item falls back unless every live item's home row
+    (128-lane rows), from its home lane on, holds the sentinel or its key,
+    and no two live items share a home row -- judged on the table as the
+    group starts. Returns (new_keys, new_counts, dropped, groups), groups
+    the (2,) int32 (groups with a live item, groups that fell back); both
+    0 when `group` is 0.
     """
     cap = table_keys.shape[0]
     sent = table_keys.dtype.type(sentinel_val)
@@ -220,10 +228,48 @@ def hash_insert_ref(table_keys: jax.Array, table_counts: jax.Array,
                                       jnp.int32(1), jnp.int32(0))
         return (tk, tc, dropped), None
 
-    (tk, tc, dropped), _ = jax.lax.scan(
-        fold_one, (table_keys, table_counts.astype(jnp.int32), jnp.int32(0)),
-        (keys, weights.astype(jnp.int32), slots.astype(jnp.int32)))
-    return tk, tc, dropped
+    xs = (keys, weights.astype(jnp.int32), slots.astype(jnp.int32))
+    init = (table_keys, table_counts.astype(jnp.int32), jnp.int32(0))
+    if not group:
+        (tk, tc, dropped), _ = jax.lax.scan(fold_one, init, xs)
+        return tk, tc, dropped, jnp.zeros((2,), jnp.int32)
+
+    lanes = 128
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    pair = ~jnp.eye(group, dtype=bool)
+
+    def group_stats(tk, gk, gw, gs):
+        live = (gk != sent) & (gw > 0)
+        rows = gs // lanes
+        home = jnp.stack([jax.lax.dynamic_slice(tk, (r * lanes,), (lanes,))
+                          for r in rows])
+        hit = (lane >= (gs % lanes)[:, None]) & (
+            (home == sent) | (home == gk[:, None]))
+        landed = jnp.all(hit.any(axis=1) | ~live)
+        clash = jnp.any((rows[:, None] == rows[None, :]) & pair
+                        & live[:, None] & live[None, :])
+        any_live = live.any()
+        return jnp.stack([any_live, any_live & (clash | ~landed)])
+
+    def fold_item(carry, x):
+        # one flat scan that judges each next group right after the item
+        # that ends the group before it (reading the table before an
+        # item's update, or in a scan nested per group, copies it)
+        tk, tc, dropped, stats = carry
+        item, last, gx = x
+        (tk, tc, dropped), _ = fold_one((tk, tc, dropped), item)
+        stats = stats + jnp.where(last, group_stats(tk, *gx), False)
+        return (tk, tc, dropped, stats), None
+
+    n = keys.shape[0]
+    grouped = tuple(x.reshape(-1, group) for x in xs)
+    nxt = tuple(jnp.repeat(jnp.roll(x, -1, axis=0), group, axis=0)
+                for x in grouped)
+    last = (jnp.arange(n) % group == group - 1) & (jnp.arange(n) < n - group)
+    stats = group_stats(table_keys, *(x[0] for x in grouped))
+    (tk, tc, dropped, stats), _ = jax.lax.scan(
+        fold_item, init + (stats.astype(jnp.int32),), (xs, last, nxt))
+    return tk, tc, dropped, stats
 
 
 def hash_lookup_ref(table_keys: jax.Array, table_counts: jax.Array,

@@ -133,6 +133,83 @@ def test_hash_insert_full_table_drops_and_counts():
     assert (gk2 == gk).all() and (gc2 == 2 * gc).all()
 
 
+def _grouped_case(case: str, cap: int):
+    """(table keys, table counts, batch keys, weights, slots, the
+    (live groups, fallbacks) crafted) of one grouped-insert case: groups of
+    8 items with home slots chosen by (row, lane) in a `cap`-slot table of
+    128-lane rows. Each case leads with a group of 8 fresh keys in 8 rows,
+    which takes the fast path."""
+    sent = np.iinfo(np.uint32).max
+    rows = cap // 128
+    tk = np.full(cap, sent, np.uint32)
+    tc = np.zeros(cap, np.int32)
+    fresh = iter(range(1, 1 << 20))
+
+    def item(row, lane, key=None, w=1):
+        return (next(fresh) if key is None else key, w, row * 128 + lane)
+
+    def spread(first_row, lane=9):
+        return [item(first_row + j, lane) for j in range(8)]
+
+    groups = [[item(j, 5 * j) for j in range(8)]]
+    want = (2, 1)
+    if case == "shared_row":            # items 2 and 5 share row 10
+        g = spread(8)
+        g[5] = item(10, 77)
+        groups.append(g)
+    elif case == "home_row_full":       # lanes 120.. of row 20 hold others
+        tk[20 * 128 + 120:21 * 128] = np.arange(900_000, 900_008)
+        tc[20 * 128 + 120:21 * 128] = 3
+        groups.append([item(20, 120)] + spread(22)[:7])
+    elif case == "same_key_twice":
+        g = spread(8)
+        g[6] = g[1]
+        groups.append(g)
+    elif case == "dead_items":          # a dead group, then half-dead one
+        dead = [(int(sent), 1, 0), (int(sent), 0, 7)] * 2 + [
+            item(12 + j, 3, w=0) for j in range(4)]
+        live = spread(12)[:4]           # weight-0 items share these rows
+        groups += [dead, live + [item(12 + j, 60, w=0) for j in range(4)]]
+        want = (2, 0)
+    elif case == "wraps":               # the last row's last lanes are full
+        tk[cap - 2:] = [900_000, 900_001]
+        tc[cap - 2:] = 1
+        groups.append([item(rows - 1, 126)] + spread(1)[:7])
+    elif case == "full_table":          # present keys add; fresh ones drop
+        tk[:] = np.arange(cap, dtype=np.uint32) + 1_000_000
+        tc[:] = 1
+        groups = [[(1_000_000 + r * 128 + 4, 2, r * 128 + 4)
+                   for r in range(8)], spread(8)]
+    else:
+        raise ValueError(case)
+    keys, w, slots = (np.asarray(x) for x in zip(*sum(groups, [])))
+    return (jnp.asarray(tk), jnp.asarray(tc), jnp.asarray(keys, np.uint32),
+            jnp.asarray(w, np.int32), jnp.asarray(slots, np.int32), want)
+
+
+@pytest.mark.parametrize("case", ["shared_row", "home_row_full",
+                                  "same_key_twice", "dead_items", "wraps",
+                                  "full_table"])
+@pytest.mark.parametrize("cap", [1 << 12, 1 << 14])
+def test_hash_insert_grouped_path(case, cap):
+    """Tables of 32 and 128 rows take the grouped path: the kernel ==
+    the sequential ref bit for bit (slot layout, drops and the group
+    counts), and the fallback counter counts exactly the groups crafted
+    to fall back."""
+    from repro.kernels import hash_table
+    sent = int(np.iinfo(np.uint32).max)
+    tk, tc, keys, w, slots, want = _grouped_case(case, cap)
+    assert hash_table.insert_group(cap, keys.shape[0]) == 8
+    got = ops.hash_insert_counted(tk, tc, keys, w, slots, sentinel_val=sent,
+                                  impl="pallas")
+    exp = ops.hash_insert_counted(tk, tc, keys, w, slots, sentinel_val=sent,
+                                  impl="ref")
+    for g, e in zip(got, exp):
+        assert (g == e).all()
+    assert tuple(int(x) for x in got[3]) == want
+    assert int(got[2]) == (8 if case == "full_table" else 0)
+
+
 @pytest.mark.parametrize("digit_bits", [2, 4, 8])
 @pytest.mark.parametrize("shift", [0, 8, 24])
 def test_radix_hist_sweep(digit_bits, shift):

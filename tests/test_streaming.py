@@ -15,6 +15,7 @@
   receiver impls.
 """
 
+import dataclasses
 import subprocess
 import sys
 
@@ -174,6 +175,25 @@ def test_kmer_counter_two_updates_equal_one_call(mesh1d):
     assert int(agg.raw_kmers) == int(st_one.raw_kmers)
     assert int(agg.sent_words) == int(st_one.sent_words)
     assert int(agg.wire_bytes) == int(st_one.wire_bytes)
+
+
+def test_insert_group_counts_reach_the_stats(reads, mesh1d):
+    """The insert's group counts ride the scan to `DAKCStats`: a 4,096-slot
+    store (32 rows) takes the grouped path, every item that reaches it lies
+    in a counted group, the one-call and incremental paths agree, and the
+    stacked receiver, which inserts nothing, counts none."""
+    cfg = fabsp.DAKCConfig(k=13, chunk_reads=32, use_l3=False,
+                           store_capacity=4096)
+    _, st = fabsp.count_kmers(reads, mesh1d, cfg)
+    groups, fallbacks = int(st.insert_groups), int(st.insert_fallbacks)
+    sent = int(st.sent_words)
+    assert sent / 8 <= groups <= sent and 0 < fallbacks < groups
+    up = fabsp.KmerCounter(mesh1d, cfg).update(reads)
+    assert (int(up.insert_groups), int(up.insert_fallbacks)) == (
+        groups, fallbacks)
+    _, stacked = fabsp.count_kmers(
+        reads, mesh1d, dataclasses.replace(cfg, receiver_impl="stacked"))
+    assert int(stacked.insert_groups) == int(stacked.insert_fallbacks) == 0
 
 
 def test_kmer_counter_grows_undersized_store(mesh1d):

@@ -95,6 +95,45 @@ def test_hash_insert_past_ceiling_is_refused(one_chip):
                  s((BATCH,), jnp.int32))
 
 
+def test_hash_insert_at_the_ceiling_matches_the_oracle_on_the_chip():
+    """On the chip only: the compiled kernel at 2^24 slots, half full, folds
+    one receive batch of the count cell's shape (104,448 NORMAL + 52,224
+    HEAVY entries, live entries first, padded by `ops.hash_insert` to a
+    multiple of 1,024) exactly as the oracle does on the host's CPU:
+    table, drops and group counts."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("runs the compiled kernel: needs a TPU")
+    from repro.core import countstore
+    rng = np.random.default_rng(15)
+    cap = CEILING
+    tk = np.full(cap, SENT, np.uint32)
+    filled = rng.random(cap) < 0.5
+    tk[filled] = rng.integers(0, 1 << 30, int(filled.sum()), dtype=np.uint32)
+    tc = np.where(filled, rng.integers(1, 9, cap), 0).astype(np.int32)
+    parts = []
+    for n, live, wmax in ((104_448, 34_000, 1), (52_224, 800, 9)):
+        k = np.full(n, SENT, np.uint32)
+        k[:live] = np.where(rng.random(live) < 0.6,
+                            rng.choice(tk[filled], live),
+                            rng.integers(0, 1 << 30, live, dtype=np.uint32))
+        w = np.zeros(n, np.int32)
+        w[:live] = rng.integers(1, wmax + 1, live)
+        parts.append((k, w))
+    keys = np.concatenate([p[0] for p in parts])
+    w = np.concatenate([p[1] for p in parts])
+    slots = np.asarray(countstore.store_slots(jnp.asarray(keys), cap))
+    args = (tk, tc, keys, w, slots)
+    got = ops.hash_insert_counted(*map(jnp.asarray, args),
+                                  sentinel_val=SENT, impl="pallas")
+    cpu = jax.devices("cpu")[0]
+    exp = ops.hash_insert_counted(*(jax.device_put(a, cpu) for a in args),
+                                  sentinel_val=SENT, impl="ref")
+    for g, e in zip(got, exp):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+    live_groups, fallbacks = (int(x) for x in got[3])
+    assert live_groups == (34_000 + 800) // 8 and 0 < fallbacks < live_groups
+
+
 @pytest.mark.parametrize("cap", [1 << 21, CEILING])
 def test_hash_lookup_compiles(one_chip, cap):
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)

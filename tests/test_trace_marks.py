@@ -126,6 +126,14 @@ def test_span_args_tie_the_spans_of_one_batch_and_flush(spans):
     assert run == [{"n_local": 512}]
 
 
+def test_commit_span_carries_the_insert_group_counts(spans):
+    commits = [a for n, _, _, a in spans if n == "kc.commit"]
+    assert [a["batch"] for a in commits] == [0, 1]
+    for a in commits:
+        assert set(a) == {"batch", "insert_groups", "insert_fallbacks"}
+        assert 0 <= a["insert_fallbacks"] <= a["insert_groups"]
+
+
 def test_spans_of_a_batch_come_in_order(spans):
     order = [n for n, *_ in spans if n.startswith("kc.")
              and n not in ("kc.update", "kc.finalize")]
