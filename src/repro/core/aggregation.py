@@ -250,6 +250,15 @@ def oneplan_bucket_key(owners, rows: int, cols: int):
     return (owners % cols) * rows + owners // cols
 
 
+def exchange(tile, axis):
+    """The collective of every route: a tiled `all_to_all` of a (P, cap)
+    tile over `axis`, under the `exchange` layer scope (nested in the
+    caller's `route`), so its device time reads apart from the partition
+    plan and the scatter around it."""
+    with jax.named_scope("exchange"):
+        return jax.lax.all_to_all(tile, axis, 0, 0, tiled=True)
+
+
 def _oneplan_two_hop(tiles, axis_names, rows: int, cols: int, capacity: int,
                      hop2_capacity: int):
     """Hop 1 + (src_col, dest_row) -> (dest_row, src_col) transpose + hop 2
@@ -262,9 +271,8 @@ def _oneplan_two_hop(tiles, axis_names, rows: int, cols: int, capacity: int,
 
     out = []
     for t in tiles:
-        h1 = swap(jax.lax.all_to_all(t, axis_names[1], 0, 0, tiled=True))
-        out.append(jax.lax.all_to_all(h1[:, :hop2_capacity], axis_names[0],
-                                      0, 0, tiled=True))
+        h1 = swap(exchange(t, axis_names[1]))
+        out.append(exchange(h1[:, :hop2_capacity], axis_names[0]))
     return out
 
 
@@ -304,9 +312,6 @@ def route_lanes(lanes, kinds, owners, valid, *, num_pes: int, capacity: int,
     slot_bytes = lane_wire_bytes(lanes, kinds)
     zero = jnp.int32(0)
 
-    def a2a(t, axis):
-        return jax.lax.all_to_all(t, axis, 0, 0, tiled=True)
-
     if grid is None:
         if hop2_capacity is not None:
             raise ValueError("hop2_capacity (compact hop 2) requires the "
@@ -314,7 +319,7 @@ def route_lanes(lanes, kinds, owners, valid, *, num_pes: int, capacity: int,
                              "second hop to compact")
         tiles, fill, ovf = route_tiles(lanes, kinds, owners, valid, num_pes,
                                        capacity, impl=impl)
-        out = tuple(a2a(t, axis_names[0]).reshape(-1) for t in tiles)
+        out = tuple(exchange(t, axis_names[0]).reshape(-1) for t in tiles)
         return RouteResult(
             lanes=out, sent_valid=fill.sum().astype(jnp.int32),
             wire_bytes=jnp.int32(num_pes * capacity * slot_bytes),
@@ -359,14 +364,14 @@ def route_lanes(lanes, kinds, owners, valid, *, num_pes: int, capacity: int,
     cap1 = capacity * rows  # per-column capacity: rows destinations share it
     tiles1, fill1, ovf1 = route_tiles(lanes, kinds, owners % cols, valid,
                                       cols, cap1, impl=impl)
-    recv1 = tuple(a2a(t, axis_names[1]).reshape(-1) for t in tiles1)
+    recv1 = tuple(exchange(t, axis_names[1]).reshape(-1) for t in tiles1)
     sent1 = jnp.array(jnp.iinfo(recv1[0].dtype).max, recv1[0].dtype)
     valid1 = recv1[0] != sent1
     dest_row = rederive_owners(recv1[0]) // cols
     cap2 = capacity * cols  # stage-2 input is cols * cap1 entries
     tiles2, fill2, ovf2 = route_tiles(recv1, kinds, dest_row, valid1, rows,
                                       cap2, impl=impl)
-    out = tuple(a2a(t, axis_names[0]).reshape(-1) for t in tiles2)
+    out = tuple(exchange(t, axis_names[0]).reshape(-1) for t in tiles2)
     return RouteResult(
         lanes=out, sent_valid=(fill1.sum() + fill2.sum()).astype(jnp.int32),
         wire_bytes=jnp.int32((cols * cap1 + rows * cap2) * slot_bytes),

@@ -1862,7 +1862,9 @@ class KmerCounter:
                 break
         with TraceAnnotation("kc.commit", batch=batch,
                              insert_groups=int(stats.insert_groups),
-                             insert_fallbacks=int(stats.insert_fallbacks)):
+                             insert_fallbacks=int(stats.insert_fallbacks),
+                             wire_bytes=int(stats.wire_bytes),
+                             load_max_over_mean=stats.load_max_over_mean):
             self._skeys, self._scounts = nk, nc
             # write the controller's final knobs back into the sticky
             # state (doubled slack and the padded-hop-2 fallback persist

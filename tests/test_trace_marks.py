@@ -7,7 +7,10 @@ The span and scope names are the ones the benchmark's reduction reads
 (`bench/scopes.py`), so a rename on either side fails here.
 """
 
+import json
+import os
 import re
+import subprocess
 import sys
 from pathlib import Path
 
@@ -130,8 +133,13 @@ def test_commit_span_carries_the_insert_group_counts(spans):
     commits = [a for n, _, _, a in spans if n == "kc.commit"]
     assert [a["batch"] for a in commits] == [0, 1]
     for a in commits:
-        assert set(a) == {"batch", "insert_groups", "insert_fallbacks"}
+        assert set(a) == {"batch", "insert_groups", "insert_fallbacks",
+                          "wire_bytes", "load_max_over_mean"}
         assert 0 <= a["insert_fallbacks"] <= a["insert_groups"]
+        # what the batch shipped: its padded tiles, and on one PE a load
+        # of exactly the mean
+        assert int(a["wire_bytes"]) > 0
+        assert float(a["load_max_over_mean"]) == pytest.approx(1.0)
 
 
 def test_spans_of_a_batch_come_in_order(spans):
@@ -191,3 +199,82 @@ def test_query_and_finalize_executables_carry_their_layer_scopes(mesh1):
     ff = fabsp._finalize_executable(cfg, mesh1, ("pe",), 1024)
     text = ff.lower(keys, counts).compile().as_text()
     assert _scopes_in(text) == {"finalize"}
+
+
+# --- the exchange scope on four devices --------------------------------------
+
+def exchange_ops():
+    """Run in a child with four CPU devices: prints, for each topology and
+    executable, (opcode, op_name) of every op that is an all-to-all or
+    whose scope path names `exchange`."""
+    from jax.sharding import Mesh
+
+    devs = np.asarray(jax.devices())
+    assert devs.size == 4
+    keys = jax.ShapeDtypeStruct((4 * 1024,), jnp.uint32)
+    counts = jax.ShapeDtypeStruct((4 * 1024,), jnp.int32)
+    out = {}
+    for topology in ("1d", "2d"):
+        axes = ("pe",) if topology == "1d" else ("row", "col")
+        mesh = Mesh(devs if topology == "1d" else devs.reshape(2, 2), axes)
+        cfg = _cfg(store_capacity=1024, topology=topology)
+        shape = (4 * 64, 60)
+        upd = fabsp._update_executable(cfg, mesh, axes, shape, "uint8",
+                                       cfg.slack, 1024)
+        qry = query._query_executable(cfg, mesh, axes, "uint32", 256, 1024)
+        texts = {
+            "local_update": upd.lower(
+                jax.ShapeDtypeStruct(shape, jnp.uint8), keys,
+                counts).compile().as_text(),
+            "local_query": qry.lower(
+                jax.ShapeDtypeStruct((4 * 256,), jnp.uint32), keys,
+                counts).compile().as_text()}
+        for exe, text in texts.items():
+            ops = []
+            for line in text.splitlines():
+                name = re.search(r'op_name="([^"]*)"', line)
+                code = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([a-z][\w-]*)\(",
+                                line)
+                if name and code and ("all-to-all" == code[1] or "exchange"
+                                      in name[1].split("/")):
+                    ops.append((code[1], name[1]))
+            out[f"{topology}/{exe}"] = ops
+    print(json.dumps(out))
+
+
+@pytest.fixture(scope="module")
+def exchange_marks():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(ROOT / "src"), str(ROOT), str(ROOT / "tests")])
+    p = subprocess.run(
+        [sys.executable, "-c",
+         "import test_trace_marks as t; t.exchange_ops()"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("topology", ["1d", "2d"])
+@pytest.mark.parametrize("exe", ["local_update", "local_query"])
+def test_exchange_scope_holds_the_all_to_alls_alone(exchange_marks,
+                                                    topology, exe):
+    """On four devices every all-to-all carries the `exchange` scope,
+    nested directly in `route`, and the scope holds nothing but the
+    lowering of the `all_to_all` primitive (the collective and the layout
+    ops made for it): its time reads apart from the partition plan and
+    the scatter."""
+    ops = exchange_marks[f"{topology}/{exe}"]
+    hops = 1 if topology == "1d" else 2
+    # a lane list per route: (word) and (word, count) per update chunk,
+    # (word, qid) out and (qid, count) back per query
+    lanes = 3 if exe == "local_update" else 4
+    assert sum(code == "all-to-all" for code, _ in ops) == hops * lanes
+    for code, name in ops:
+        parts = name.split("/")
+        assert "exchange" in parts, (code, name)
+        i = parts.index("exchange")
+        assert parts[i - 1] == "route" and parts[i + 1:] == ["all_to_all"], \
+            name
+        assert parts[0] == f"jit({exe})"
